@@ -136,17 +136,19 @@ ENV_VARS: Tuple[EnvVar, ...] = (
         "FABRIC_TPU_KERNEL_VARIANT", "enum(inline|micro|microcond|auto)",
         "auto",
         "ops/p256_kernel.py _kernel_variant",
-        "force the ECDSA kernel trace shape; auto picks micro off-CPU "
-        "(small enough for the remote-compile service) and inline on "
-        "CPU",
+        "force the ECDSA kernel trace shape; auto is per backend "
+        "(_AUTO_VARIANT: inline on CPU and, provisionally, on TPU — "
+        "ROADMAP D3)",
     ),
     EnvVar(
-        "FABRIC_TPU_CIOS_UNROLL", "enum(0|1)", "(auto: unrolled off-CPU)",
-        "ops/bignum.py _unroll_cios (bench.py and tests/conftest.py pin "
-        "it)",
+        "FABRIC_TPU_CIOS_UNROLL", "enum(0|1)", "(auto: looped)",
+        "ops/bignum.py _cios_unrolled (bench.py and tests/conftest.py "
+        "pin it)",
         "force the CIOS Montgomery multiply trace shape: 1 = 20 "
-        "unrolled iterations (fastest at runtime), 0 = lax.fori_loop "
-        "(10x faster to compile on CPU)",
+        "unrolled iterations (one flat DAG; the whole verify program "
+        "then takes the TPU compiler over half an hour), 0 = "
+        "lax.fori_loop (compiles in minutes); auto is per backend "
+        "(_AUTO_CIOS_UNROLLED, provisional — ROADMAP D3)",
     ),
     # -- host crypto pools ----------------------------------------------
     EnvVar(

@@ -1,8 +1,8 @@
 """Deterministic fault injection for the fabchaos harness.
 
 The runtime carries named *fault points* at its failure seams — the
-places where production traffic actually breaks (BENCH_r04/r05: backend
-init, pool breakage, transport flaps):
+places where production traffic actually breaks (backend init, pool
+breakage, transport flaps):
 
 =========================  ==================================================
 site                       seam

@@ -100,8 +100,7 @@ class ShardedVerify:
             )
         if self._flat is None:
             self._flat = self._build_flat()
-        with self.mesh:
-            return np.asarray(self._flat(e, r, s, qx, qy, ok))
+        return np.asarray(self._flat(e, r, s, qx, qy, ok))
 
     def verify_channels(
         self,
@@ -121,8 +120,7 @@ class ShardedVerify:
             )
         if self._channels is None:
             self._channels = self._build_channels()
-        with self.mesh:
-            return np.asarray(self._channels(e, r, s, qx, qy, ok))
+        return np.asarray(self._channels(e, r, s, qx, qy, ok))
 
 
 def channel_stack(

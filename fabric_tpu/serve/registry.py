@@ -80,14 +80,16 @@ _AOT_COMPILE_LOCK = threading.Lock()
 class _CompileCounters:
     """Process-wide jax compile/cache-event accounting.
 
-    Counts ``/jax/...`` monitoring events whose name marks a backend
-    compile or a persistent-cache hit.  One listener for the process
-    (jax's listener list only grows); readers snapshot-and-diff."""
+    One listener for the process (jax's listener list only grows);
+    readers snapshot-and-diff.  JAX 0.9 times ``compile_or_get_cached``
+    as a whole under ``backend_compile_duration``, so that event fires
+    for a persistent-cache HIT too (with the retrieval time): a real
+    XLA compile is a compile request that was not a hit."""
 
     _lock = threading.Lock()
     _installed = False
-    compiles = 0
-    cache_hits = 0
+    requests = 0  # compile_or_get_cached calls
+    cache_hits = 0  # ... of which served from the persistent cache
 
     @classmethod
     def install(cls) -> None:
@@ -98,22 +100,20 @@ class _CompileCounters:
         import jax
 
         def _on_event(event: str, **kwargs) -> None:
-            # '/jax/compilation_cache/cache_hits' fires per persistent-
-            # cache hit; backend_compile duration events fire per real
-            # XLA compile.  Counter writes are GIL-atomic int adds.
             if "cache_hit" in event:
                 cls.cache_hits += 1  # GIL-atomic int add, monotonic counter
 
         def _on_duration(event: str, duration: float, **kwargs) -> None:
             if "backend_compile" in event:
-                cls.compiles += 1  # GIL-atomic int add, monotonic counter
+                cls.requests += 1  # GIL-atomic int add, monotonic counter
 
         jax.monitoring.register_event_listener(_on_event)
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
     @classmethod
     def snapshot(cls) -> Tuple[int, int]:
-        return cls.compiles, cls.cache_hits
+        """(real XLA compiles, persistent-cache hits) so far."""
+        return cls.requests - cls.cache_hits, cls.cache_hits
 
 
 class BucketProgramRegistry:
@@ -320,13 +320,19 @@ def _load_aot(path: str) -> Optional[Callable]:
     never correctness.  The artifact directory is operator-owned cache
     state (same trust domain as ``.jax_cache`` itself)."""
     try:
+        import jax
         from jax.experimental import serialize_executable as se
 
         with open(path, "rb") as fh:
             trees_len = int.from_bytes(fh.read(8), "big")
             in_tree, out_tree = pickle.loads(fh.read(trees_len))  # fabwire: disable=unbounded-wire-alloc  # operator-owned AOT cache in the same trust domain as .jax_cache: fh.read caps at file EOF and any short/garbled artifact falls into the recompile path below
             blob = fh.read()
-        return se.deserialize_and_load(blob, in_tree, out_tree)
+        # the builder compiles for the default device (its shapes carry
+        # no sharding); without this JAX loads the artifact for EVERY
+        # device of the backend and the first call wants one shard each
+        return se.deserialize_and_load(
+            blob, in_tree, out_tree, execution_devices=jax.devices()[:1]
+        )
     except FileNotFoundError:
         return None
     except Exception as exc:  # noqa: BLE001 - stale artifact: rebuild

@@ -1,8 +1,7 @@
 """fabchaos — deterministic fault-injection + adversarial traffic harness.
 
 The bench suite measures clean, uniform batches; production variance
-comes from faults (BENCH_r04/r05: backend init hangs, pool breakage,
-device loss) and from adversarial traffic (skewed channels, invalid
+comes from faults (backend init hangs, pool breakage, device loss) and from adversarial traffic (skewed channels, invalid
 endorsements, MVCC storms, CRL rotation, malformed blocks).  fabchaos
 drives the REAL runtime objects — VerifyBatcher, SoftwareProvider,
 CommitPipeline, BlockValidator, the MVCC validator, BlockDeliverer —

@@ -2,11 +2,11 @@
 
 The native library accelerates the irregular byte work feeding the TPU
 kernels — batched SHA-256 and strict-DER ECDSA signature parsing — and
-is optional: when the shared object is missing (or the build toolchain
-is absent) every entry point falls back to the pure-Python
-implementation with identical semantics, so nothing above this module
-needs to care. Build with ``make -C native`` (attempted automatically
-once per process).
+is optional: when the shared object cannot be built or loaded every
+entry point falls back to the pure-Python implementation with identical
+semantics, so nothing above this module needs to care. Built with
+``make -C native`` automatically, once per process, when the shared
+object is missing or older than its sources.
 """
 
 from __future__ import annotations
@@ -31,41 +31,36 @@ _SO_PATH = os.path.join(_REPO, "native", "libfabric_native.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_why_unavailable: Optional[str] = None
+
+
+def _stale() -> bool:
+    """The .so is missing, or older than a file it is built from — so
+    what runs is always built from the files git would commit (the .so
+    itself is gitignored and may linger on a disk across checkouts)."""
+    native_dir = os.path.dirname(_SO_PATH)
+    try:
+        built = os.path.getmtime(_SO_PATH)
+        return any(
+            os.path.getmtime(os.path.join(native_dir, name)) > built
+            for name in os.listdir(native_dir)
+            if name.endswith((".cc", ".h")) or name == "Makefile"
+        )
+    except OSError:
+        return True
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _why_unavailable
     with _lock:
         if _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO_PATH):
-            try:
-                subprocess.run(
-                    ["make", "-C", os.path.dirname(_SO_PATH)],
-                    capture_output=True,
-                    timeout=120,
-                    check=True,
-                )
-            except Exception as exc:
-                logger.warning(
-                    "native library build failed (%s); using the Python "
-                    "parsers", exc,
-                )
-                return None
-        try:
-            lib = ctypes.CDLL(_SO_PATH)
-        except OSError:
-            return None
-        if not hasattr(lib, "fn_block_parse"):
-            # stale prebuilt .so predating the block parser: rebuild and
-            # reload. Safe because the Makefile compiles to a temp file
-            # and renames — the inode the stale handle has mapped is
-            # never rewritten (no SIGBUS), and the renamed path is a NEW
-            # inode, so dlopen (which dedups by dev:ino) returns a fresh
-            # handle rather than the stale one. On any failure the stale
-            # handle keeps serving der/sha and block parsing falls back
-            # to the Python parser (consumers gate on hasattr).
+        if _stale():
+            # -B: make's own rule does not list the Makefile. Safe under
+            # a process that has the old .so mapped: the Makefile
+            # compiles to a temp file and renames, so the mapped inode
+            # is never rewritten (no SIGBUS).
             try:
                 subprocess.run(
                     ["make", "-C", os.path.dirname(_SO_PATH), "-B"],
@@ -73,12 +68,18 @@ def _load() -> Optional[ctypes.CDLL]:
                     timeout=120,
                     check=True,
                 )
-                lib = ctypes.CDLL(_SO_PATH)
             except Exception as exc:
+                _why_unavailable = f"build failed: {exc}"
                 logger.warning(
-                    "stale native library rebuild failed (%s); block "
-                    "parsing falls back to the Python parser", exc,
+                    "native library build failed (%s); using the Python "
+                    "parsers", exc,
                 )
+                return None
+        try:
+            lib = ctypes.CDLL(_SO_PATH)
+        except OSError as exc:
+            _why_unavailable = f"load failed: {exc}"
+            return None
         u8p = ctypes.POINTER(ctypes.c_uint8)
         u64p = ctypes.POINTER(ctypes.c_uint64)
         lib.fn_batch_sha256.argtypes = [u8p, u64p, u64p, ctypes.c_int64, u8p]
@@ -114,8 +115,9 @@ def _load() -> Optional[ctypes.CDLL]:
             lib.fn_block_free.restype = None
             lib.fn_sha256_backend.restype = ctypes.c_int
         except AttributeError:
-            # still missing after the rebuild attempt above: serve
-            # der/sha only; block parsing uses the Python fallback
+            # a library without the block parser: serve der/sha only;
+            # block parsing uses the Python fallback (consumers gate on
+            # hasattr)
             pass
         _lib = lib
         return _lib
@@ -123,6 +125,12 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def why_unavailable() -> Optional[str]:
+    """Why available() is False (build or load error), else None."""
+    _load()
+    return _why_unavailable
 
 
 def _pack(chunks: Sequence[bytes]):

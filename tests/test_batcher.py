@@ -189,7 +189,7 @@ def test_batching_provider_adapter():
 
 
 class SlowResolveProvider:
-    """Fixed per-launch 'RTT' in the resolver (tunnel simulation)."""
+    """Fixed per-launch 'RTT' in the resolver (a high-latency launch path)."""
 
     def __init__(self, rtt_s):
         self.rtt_s = rtt_s
@@ -250,7 +250,7 @@ def test_forced_mode_env(monkeypatch):
 
 
 class HangingResolveProvider:
-    """Resolver blocks until released — a wedged device tunnel."""
+    """Resolver blocks until released — a wedged device."""
 
     def __init__(self):
         self.release = threading.Event()

@@ -56,7 +56,7 @@ def test_init_error_cached_as_failure(monkeypatch):
     def worker():
         with mod._lock:
             mod._state["status"] = "error"
-            mod._state["error"] = "UNAVAILABLE: tunnel down"
+            mod._state["error"] = "UNAVAILABLE: backend init failed"
 
     monkeypatch.setattr(mod, "_worker", worker)
     assert mod.probe_devices(2.0) is None

@@ -131,3 +131,28 @@ def test_der_parse_matches_python_fallback():
 
     for a, b in zip(native_out, fallback_out):
         assert np.array_equal(a, b)
+
+
+def test_stale_when_missing_or_older_than_any_source(tmp_path, monkeypatch):
+    """PR 22: the .so is (re)built when it is missing or older than any
+    of native/*.cc, native/*.h, native/Makefile — what runs is built
+    from the files git would commit, not from a library that lingers on
+    a disk."""
+    import os
+
+    so = tmp_path / "libfabric_native.so"
+    monkeypatch.setattr(native, "_SO_PATH", str(so))
+    assert native._stale()  # missing
+    for name in ("a.cc", "b.h", "Makefile", "notes.txt"):
+        (tmp_path / name).write_text("x")
+        os.utime(tmp_path / name, (1000, 1000))
+    so.write_text("so")
+    os.utime(so, (2000, 2000))
+    assert not native._stale()
+    os.utime(tmp_path / "notes.txt", (3000, 3000))  # not a source
+    assert not native._stale()
+    for name in ("a.cc", "b.h", "Makefile"):
+        os.utime(tmp_path / name, (3000, 3000))
+        assert native._stale(), name
+        os.utime(tmp_path / name, (1000, 1000))
+    assert native.why_unavailable() is None or not native.available()

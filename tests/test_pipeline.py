@@ -345,7 +345,7 @@ def test_channel_prepare_dispatches_async_and_store_resolves(
     flags = ch.store_block(block, prepared=prepared)
     assert prov.resolved == 1
     assert ch.ledger.height == 1
-    assert bytes(flags) == b"\x00" * 3, "async-prepared masks not VALID"
+    assert flags.tobytes() == b"\x00" * 3, "async-prepared masks not VALID"
 
 
 def test_channel_async_resolver_failure_fails_closed(tmp_path, world):

@@ -112,3 +112,44 @@ def test_key_columns_vectorized_matches_per_key_reference():
     assert list(idx2) == list(idx) and list(on_curve2) == list(on_curve)
     assert all(np.array_equal(a, b) for a, b in zip(kx, kx2))
     assert all(np.array_equal(a, b) for a, b in zip(ky, ky2))
+
+
+@pytest.mark.parametrize("tier", ["fastec", "hostec_np", "hostec", "p256"])
+def test_no_key_lane_is_false_on_every_ec_tier(tier):
+    """A lane whose key is None (identity or SEC1 import failed upstream,
+    serve NO_KEY) is a False lane — never an error that fails the
+    batch's good lanes.  PR 22: on the fastec tier Provider.batch_verify
+    raised AttributeError here, which failed the sidecar client's
+    degrade target (and chip_smoke's oracle) closed."""
+    from fabric_tpu.crypto.bccsp import (
+        available_ec_backends,
+        ec_backend_name,
+        select_ec_backend,
+    )
+
+    if not available_ec_backends()[tier]:
+        pytest.skip(f"EC tier {tier} not importable here")
+    (key, sig, dig), (_, bad_sig, bad_dig) = _cases(2, 1)
+    before = ec_backend_name()
+    select_ec_backend(tier)
+    try:
+        sw = SoftwareProvider()
+        keys, sigs, digs = [key, None, key], [sig, sig, bad_sig], [dig, dig, bad_dig]
+        assert sw.batch_verify(keys, sigs, digs) == [True, False, False]
+        assert sw.batch_verify_async(keys, sigs, digs)() == [True, False, False]
+    finally:
+        select_ec_backend(before)
+
+
+def test_no_key_lane_is_dead_in_device_prep():
+    """TPUProvider's host prep maps a None key to an off-curve column, so
+    the host mask kills the lane before the kernel (PR 22: it raised
+    AttributeError, and the batch degraded to software)."""
+    (key, sig, dig), _ = _cases(2, 1)
+    prov = TPUProvider.__new__(TPUProvider)  # no device/jax needed
+    prov._key_limb_cache = {}
+    prep, limbs = prov.prep_bytes([key, None, key], [sig] * 3, [dig] * 3)
+    assert limbs is None
+    assert list(prep[-1]) == [True, False, True]
+    *_, ok = prov.prep_limbs([None, key], [sig] * 2, [dig] * 2)
+    assert list(ok) == [False, True]
