@@ -1,0 +1,14 @@
+"""verify_roofline.commit: the least time the chip could take for the useful
+lanes of one launch (trace_reduce.ops_per_verify over the int8 peak; the
+operations bound, the bytes bound is far below it), over the device time of
+one launch of ``jit_verify_batch_bytes_device``.
+Layer: kernel.  Moves: commit_tx_per_s."""
+
+from benchmarks import layer_readers as readers
+
+PROGRAM = "jit_verify_batch_bytes_device"
+MOVES = "commit_tx_per_s"
+
+
+def read(ctx):
+    return readers.program_roofline_pct(ctx, PROGRAM)
