@@ -1,0 +1,15 @@
+"""idle_unattributed_ms.serve: device-idle ms per launch-to-launch cycle of the
+traced slice lying under none of the program's annotations of the classes
+before it (span_readers.idle_ms_by_class: every instant of a gap goes to one
+class, so the three idle_*_ms.serve add up to device_idle_pct.serve x the
+traced cycle).
+Layer: device.  Moves: verdict_lanes_per_s."""
+
+from benchmarks import span_readers as spans
+
+CLASS = "unattributed"
+MOVES = "verdict_lanes_per_s"
+
+
+def read(ctx):
+    return spans.idle_ms_per_cycle(ctx, CLASS)

@@ -16,11 +16,23 @@ hook drives two layers at once:
 2. **Spans + flight recorder** — ``span(name)`` context managers with
    monotonic clocks, thread-propagated parent links (a
    ``threading.local`` stack; cross-thread hand-offs pass an explicit
-   ``parent=``), all landing in a bounded ring buffer.  ``dump()``
-   renders the ring as Chrome trace-event JSON (``chrome://tracing`` /
-   Perfetto); :func:`obs_trigger` snapshots it to disk automatically on
+   ``parent=``), all landing in a bounded ring buffer.  A child takes
+   its parent's ``block`` / ``req_id`` / ``req_ids`` unless it names its
+   own, so every span of one block or one sidecar request carries the
+   same identifier.  ``record_span(name, t0, t1)`` writes a span whose
+   start lies in the past or on another thread (a queue wait, clock
+   reads a function already takes).  ``dump()`` renders the ring as
+   Chrome trace-event JSON (``chrome://tracing`` / Perfetto);
+   :func:`obs_trigger` snapshots it to disk automatically on
    degrade/fail-closed events so the moments worth debugging are the
    moments that self-record.
+
+   **One clock with the device trace**: in a process that has imported
+   JAX, an executed span also enters and exits a
+   ``jax.profiler.TraceAnnotation`` of its own name (attributes stay in
+   the ring), so a profiler session sees the program's spans on the
+   trace's host lines.  fabobs imports no JAX: it finds the module in
+   ``sys.modules`` or does nothing.
 
 Mask safety contract (this file rides the fabflow MASK tier): no
 function here produces or transforms a verdict mask, and every enabled
@@ -45,10 +57,13 @@ or from the environment (same warn-never-raise discipline as
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
+import types
 from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
@@ -65,9 +80,7 @@ LATENCY_BUCKETS = metrics_mod.DEFAULT_BUCKETS
 LANE_BUCKETS = (1.0, 8.0, 64.0, 256.0, 1024.0, 4096.0, 16384.0, 65536.0)
 # pipeline-stage latency: the default ladder extended downward — warm
 # host-ladder prepare sits in the sub-millisecond range the 5ms lowest
-# default bucket would flatten.  ONE definition shared by the /metrics
-# series AND peer/pipeline's embedded stage_stats state, so the two
-# surfaces can never quantize the same stage differently.
+# default bucket would flatten.
 STAGE_BUCKETS = (0.0005, 0.001, 0.0025) + LATENCY_BUCKETS
 
 
@@ -135,7 +148,9 @@ CANONICAL_METRICS: Tuple[MetricSpec, ...] = (
     ),
     MetricSpec(
         "fabric_verify_seconds", "histogram", ("rung",),
-        "batch verify wall time per ladder rung",
+        "batch verify time per ladder rung (device: the provider's own "
+        "host prep + dispatch + resolve, not the time a resolver waited "
+        "to be called; other rungs: wall time)",
         "crypto/bccsp.py, crypto/tpu_provider.py, serve/client.py",
         LATENCY_BUCKETS,
     ),
@@ -327,6 +342,39 @@ def current_span() -> Optional["Span"]:
     return stack[-1] if stack else None
 
 
+# what a child span takes from its parent unless it names its own: the
+# identifier that ties every span of one block, or of one sidecar request
+# (``req_ids``: of one coalesced launch), together
+_INHERITED_ATTRS = ("block", "req_id", "req_ids")
+
+
+def _adopt(attrs: Dict, explicit: Optional["Span"]) -> int:
+    """Find a new span's parent (the one handed over, unless it is absent
+    or the no-op span; else the thread's open span), copy the inherited
+    identifiers into ``attrs`` and return the parent's id (0: a root)."""
+    parent = explicit
+    if parent is None or not parent.span_id:
+        parent = current_span()
+    if parent is None:
+        return 0
+    for key in _INHERITED_ATTRS:
+        if key not in attrs and key in parent.attrs:
+            attrs[key] = parent.attrs[key]
+    return parent.span_id
+
+
+def _enter_annotation(name: str):
+    """The span on the profiler's clock: an entered
+    ``jax.profiler.TraceAnnotation(name)``, or None in a process that
+    never imported JAX (it has no device trace to align with)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    annotation = jax.profiler.TraceAnnotation(name)
+    annotation.__enter__()
+    return annotation
+
+
 class Span:
     """One timed section.  Entering pushes it on the thread's span
     stack; exiting records a Chrome ``ph:"X"`` complete event into the
@@ -334,38 +382,58 @@ class Span:
     swallowed (``_swallow``); exceptions from the *wrapped* code
     propagate untouched — a span can never eat a verify error."""
 
-    __slots__ = ("name", "attrs", "span_id", "parent_id", "_reg", "_t0")
+    __slots__ = (
+        "name", "attrs", "span_id", "parent_id", "_reg", "_t0", "_parent",
+        "_annotation",
+    )
 
     def __init__(self, reg: "ObsRegistry", name: str, attrs: Dict,
                  parent: Optional["Span"] = None):
         self._reg = reg
         self.name = name
         self.attrs = attrs
-        self.span_id = reg._next_span_id()
-        self.parent_id = parent.span_id if parent is not None else 0
+        self.span_id = next(reg._span_ids)
+        self.parent_id = 0
+        self._parent = parent
         self._t0 = 0.0
+        self._annotation = None
+
+    def set(self, **attrs) -> None:
+        """Attributes the wrapped code learns late (an id the section
+        itself allocates).  They land in the ring record even when the
+        span has already exited: the record keeps this very dict."""
+        try:
+            self.attrs.update(attrs)
+        except Exception as exc:  # noqa: BLE001 - obs must never raise
+            self._reg._swallow("span.set", exc)
 
     def __enter__(self) -> "Span":
         try:
-            if self.parent_id == 0:
-                cur = current_span()
-                if cur is not None:
-                    self.parent_id = cur.span_id
+            self.parent_id = _adopt(self.attrs, self._parent)
             _span_stack().append(self)
-            self._t0 = time.perf_counter()
         except Exception as exc:  # noqa: BLE001 - obs must never raise
             self._reg._swallow("span.enter", exc)
+        try:
+            self._annotation = _enter_annotation(self.name)
+        except Exception as exc:  # noqa: BLE001 - obs must never raise
+            self._reg._swallow("span.annotate", exc)
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        t1 = time.perf_counter()
         try:
-            t1 = time.perf_counter()
+            if self._annotation is not None:
+                self._annotation.__exit__(exc_type, exc, tb)
+        except Exception as swallow_exc:  # noqa: BLE001 - obs must never raise
+            self._reg._swallow("span.annotate", swallow_exc)
+        try:
             stack = _span_stack()
             if stack and stack[-1] is self:
                 stack.pop()
             elif self in stack:  # tolerate mis-nested exits
                 stack.remove(self)
-            args = dict(self.attrs)
+            args = self.attrs  # not a copy: see set()
             args["span_id"] = self.span_id
             if self.parent_id:
                 args["parent_id"] = self.parent_id
@@ -394,6 +462,10 @@ class _NoopSpan:
     name = "noop"
     span_id = 0
     parent_id = 0
+    attrs = types.MappingProxyType({})
+
+    def set(self, **attrs) -> None:
+        return None
 
     def __enter__(self) -> "_NoopSpan":
         return self
@@ -429,7 +501,7 @@ class ObsRegistry:
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=max(16, int(ring)))
         self._epoch = time.perf_counter()
-        self._span_seq = 0
+        self._span_ids = itertools.count(1)  # next() is GIL-atomic
         self._dumps = 0
         self._dumped_paths: List[str] = []
         self.dropped = 0  # obs failures swallowed (self-accounting)
@@ -518,6 +590,31 @@ class ObsRegistry:
             self._swallow(name, exc)
             return _NOOP_SPAN  # type: ignore[return-value]
 
+    def record_span(self, name: str, t0: float, t1: float,
+                    parent: Optional[Span] = None, **attrs) -> None:
+        """A span nobody executed: ``t0``/``t1`` are ``time.perf_counter``
+        reads the caller already holds, maybe taken on another thread (a
+        queue wait, back-pressure, the phases of a function that times
+        itself).  Parent and inherited attributes as for ``span()``;
+        no ``TraceAnnotation`` (the profiler cannot be told of the
+        past)."""
+        try:
+            parent_id = _adopt(attrs, parent)
+            attrs["span_id"] = next(self._span_ids)
+            if parent_id:
+                attrs["parent_id"] = parent_id
+            self._record_event(
+                {
+                    "name": name,
+                    "ph": "X",
+                    "ts": self._us(t0),
+                    "dur": round((t1 - t0) * 1e6, 1),
+                    "args": attrs,
+                }
+            )
+        except Exception as exc:  # noqa: BLE001 - obs must never raise
+            self._swallow(name, exc)
+
     def event(self, name: str, **attrs) -> None:
         """Instant flight-recorder mark (Chrome ``ph:"i"``)."""
         try:
@@ -566,11 +663,6 @@ class ObsRegistry:
     def _us(self, t: float) -> float:
         return round((t - self._epoch) * 1e6, 1)
 
-    def _next_span_id(self) -> int:
-        with self._lock:
-            self._span_seq += 1
-            return self._span_seq
-
     def _record_event(self, record: Dict) -> None:
         record.setdefault("pid", os.getpid())
         record.setdefault("tid", threading.get_ident())
@@ -578,8 +670,10 @@ class ObsRegistry:
             self._ring.append(record)
 
     def trace_events(self) -> List[Dict]:
+        # args copied too: a span's record keeps the span's own dict, which
+        # Span.set() may still write to
         with self._lock:
-            return [dict(r) for r in self._ring]
+            return [dict(r, args=dict(r["args"])) for r in self._ring]
 
     def dump(self, path: Optional[str] = None) -> str:
         """Chrome trace-event JSON of the flight ring (load it in
@@ -776,6 +870,16 @@ def span(name: str, parent: Optional[Span] = None, **attrs):
     if reg is None:
         return _NOOP_SPAN
     return reg.span(name, parent=parent, **attrs)
+
+
+def obs_record_span(name: str, t0: float, t1: float,
+                    parent: Optional[Span] = None, **attrs) -> None:
+    """A span from two ``time.perf_counter`` reads the caller already
+    holds (see :meth:`ObsRegistry.record_span`)."""
+    reg = _OBS
+    if reg is None:
+        return
+    reg.record_span(name, t0, t1, parent=parent, **attrs)
 
 
 def obs_event(name: str, **attrs) -> None:

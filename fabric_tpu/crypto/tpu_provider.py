@@ -17,7 +17,8 @@ from __future__ import annotations
 
 import os
 import time
-from typing import Dict, List, Sequence, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from typing import Dict, List, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -36,6 +37,25 @@ _BUCKETS = [128, 256, 512, 1024, 2048, 4096, 8192, 16384]
 
 # stand-in for a lane with no key: (0, 0) is not on P-256
 _NO_KEY = ECDSAPublicKey(0, 0)
+
+
+def _on_fresh_stack(fn, *args):
+    """``fn(*args)`` on a new thread's empty Python stack: its result, or
+    its exception raised here.
+
+    Tracing and lowering the verify program is deep Python recursion
+    over ~10^5 equations, and CPython (3.11 on) keeps frames in 16 KiB
+    data-stack chunks: where the caller's own frames put a chunk
+    boundary inside that recursion's hot depth, every call across it
+    allocates and frees a chunk (a million page faults in one lowering).
+    Lowering the one program took 36 s from the sidecar's stack and 59,
+    83 and 93 s from three peer-side stacks that differ by a few local
+    variables and one frame, against 7 s from an empty one (chip host,
+    PR 28) — which is what refused PR 27."""
+    with ThreadPoolExecutor(
+        max_workers=1, thread_name_prefix="tpu-first-dispatch"
+    ) as fresh:
+        return fresh.submit(fn, *args).result()
 
 
 def _bucket(n: int) -> int:
@@ -191,16 +211,20 @@ class TPUProvider(Provider):
         the accelerator went away."""
         n = len(signatures)
         t0 = time.perf_counter()
-        prep, limbs = self.prep_bytes(keys, signatures, digests)
+        with fabobs.span("tpu.prep", lanes=n) as prep_span:
+            prep, limbs = self.prep_bytes(keys, signatures, digests)
+            if prep_span.span_id and prep is not None and n:
+                prep_span.set(distinct_keys=int(prep[5].max()) + 1)
         attempts = max(int(os.environ.get("FABRIC_TPU_DISPATCH_RETRIES", "3")), 1)
         delay = 1.0
         out = None
         for attempt in range(attempts):
             try:
-                if prep is None:  # key-bucket overflow: limb-matrix path
-                    out = self._dispatch_limbs(limbs)
-                else:
-                    out = self._dispatch_bytes_or_fallback(prep)
+                with fabobs.span("tpu.dispatch", lanes=n, bucket=_bucket(n)):
+                    if prep is None:  # key-bucket overflow: limb-matrix path
+                        out = self._dispatch_limbs(limbs)
+                    else:
+                        out = self._dispatch_bytes_or_fallback(prep)
                 break
             except Exception as exc:  # noqa: BLE001 - backend init/dispatch flake
                 if attempt == attempts - 1:
@@ -211,10 +235,13 @@ class TPUProvider(Provider):
                     return lambda: self._sw_verify_all(keys, signatures, digests)
                 time.sleep(delay)
                 delay *= 3.0
+        host_s = time.perf_counter() - t0
 
         def resolve() -> List[bool]:
+            r0 = time.perf_counter()
             try:
-                verdicts = [bool(v) for v in np.asarray(out)[:n]]
+                with fabobs.span("tpu.resolve", lanes=n):
+                    verdicts = [bool(v) for v in np.asarray(out)[:n]]
             except Exception as exc:  # noqa: BLE001 - async error surfaces here
                 logger.warning(
                     "async device result failed (%s); "
@@ -222,9 +249,11 @@ class TPUProvider(Provider):
                 )
                 return self._sw_verify_all(keys, signatures, digests)
             fabobs.obs_count("fabric_verify_lanes_total", n, rung="device")
+            # the provider's own time: host prep + dispatch + this wait and
+            # copy back, not the time the resolver sat waiting to be called
             fabobs.obs_observe(
                 "fabric_verify_seconds",
-                time.perf_counter() - t0, rung="device",
+                host_s + time.perf_counter() - r0, rung="device",
             )
             return verdicts
 
@@ -352,7 +381,8 @@ class TPUProvider(Provider):
             widths = [(0, pad)] + [(0, 0)] * (a.ndim - 1)
             return np.pad(a, widths)
 
-        return self._pk.verify_batch_bytes_jit(
+        return self._lower_on_fresh_stack_then_call(
+            self._pk.verify_batch_bytes_jit, ("bytes", size),
             padded(e_bytes),
             padded(r_bytes),
             padded(s_bytes),
@@ -363,8 +393,26 @@ class TPUProvider(Provider):
         )
 
     def _dispatch_limbs(self, limbs: Sequence[np.ndarray]):
-        n = limbs[-1].shape[0]
-        return self._pk.verify_batch_jit(*self.pad_limbs(limbs, _bucket(n)))
+        size = _bucket(limbs[-1].shape[0])
+        return self._lower_on_fresh_stack_then_call(
+            self._pk.verify_batch_jit, ("limbs", size),
+            *self.pad_limbs(limbs, size),
+        )
+
+    # (program, bucket) shapes this process has traced and lowered: jit's
+    # cache is process-wide, so the set is the class's
+    _lowered: Set[Tuple[str, int]] = set()
+
+    def _lower_on_fresh_stack_then_call(self, program, shape, *args):
+        """Dispatch the jitted `program`.  Before the first call of a
+        shape, the program is traced and lowered for it through
+        :func:`_on_fresh_stack`; the call itself, here, finds both cached
+        and only compiles or loads (which took five times as long on
+        that thread as from a caller's stack: chip host, PR 28)."""
+        if shape not in self._lowered:
+            _on_fresh_stack(program.lower, *args)
+            self._lowered.add(shape)
+        return program(*args)
 
     def prep_limbs(
         self,

@@ -58,11 +58,11 @@ logger = must_get_logger("batcher")
 class _Request:
     __slots__ = (
         "keys", "sigs", "digests", "event", "result", "error", "permits",
-        "t_submit", "on_dispatch", "deadline_s",
+        "t_submit", "on_dispatch", "deadline_s", "parent",
     )
 
     def __init__(self, keys, sigs, digests, on_dispatch=None,
-                 deadline_s=None):
+                 deadline_s=None, parent=None):
         self.keys = keys
         self.sigs = sigs
         self.digests = digests
@@ -81,6 +81,9 @@ class _Request:
         # TIGHTEST deadline in the batch — lanes with a live budget are
         # launched, never lingered past it.
         self.deadline_s = deadline_s
+        # the submitter's fabobs span (serve.verify, pipeline.prepare):
+        # what the dispatcher thread records for this request links to it
+        self.parent = parent
 
     def resolve(self) -> List[bool]:
         self.event.wait()  # fablife: disable=blocking-unbudgeted  # bounded by the batcher lifetime, not a wire budget: stop() settles every admitted request fail-closed (event.set), so this wait can never outlive the batcher; wire deadlines cap it upstream via deadline_s
@@ -213,6 +216,7 @@ class VerifyBatcher:
         digests: Sequence[bytes],
         on_dispatch: Optional[Callable[[], None]] = None,
         deadline_s: Optional[float] = None,
+        parent=None,
     ) -> Optional[Callable[[], List[bool]]]:
         """Non-blocking admission (the serve sidecar's front door): the
         resolver when the lane budget admits the request NOW, else None
@@ -222,10 +226,13 @@ class VerifyBatcher:
         (the moment its lane permits are released) — callers keeping a
         parallel admission ledger release theirs in the same window.
         ``deadline_s`` (absolute ``time.monotonic()``) caps how long the
-        dispatcher may linger this request for coalescing company."""
+        dispatcher may linger this request for coalescing company.
+        ``parent`` is the caller's fabobs span where it is not the
+        thread's current one (the sidecar opens ``serve.verify`` after
+        admission)."""
         return self._admit(
             keys, signatures, digests, block=False, on_dispatch=on_dispatch,
-            deadline_s=deadline_s,
+            deadline_s=deadline_s, parent=parent,
         )
 
     def _admit(
@@ -236,6 +243,7 @@ class VerifyBatcher:
         block: bool,
         on_dispatch: Optional[Callable[[], None]] = None,
         deadline_s: Optional[float] = None,
+        parent=None,
     ) -> Optional[Callable[[], List[bool]]]:
         n = len(keys)
         if n == 0:
@@ -250,6 +258,7 @@ class VerifyBatcher:
         req = _Request(
             list(keys), list(signatures), list(digests),
             on_dispatch=on_dispatch, deadline_s=deadline_s,
+            parent=parent if parent is not None else fabobs.current_span(),
         )
         req.permits = min(n, self._max_pending_lanes)
         with self._lanes_cv:
@@ -328,7 +337,7 @@ class VerifyBatcher:
         return batch
 
     def _run(self) -> None:
-        # entries: (requests, resolver, dispatch_time, lanes)
+        # entries: (requests, resolver, dispatch_time, lanes, launch span)
         pending: List[Tuple] = []
         while True:
             batch = self._take_batch()
@@ -354,10 +363,25 @@ class VerifyBatcher:
                         r.on_dispatch()
                     except Exception as exc:  # noqa: BLE001 - a ledger hook must never kill the dispatcher
                         logger.warning("on_dispatch hook failed: %s", exc)
+            # admission, the dispatcher's pick-up and the linger window
+            t_launch = time.perf_counter()
+            for r in batch:
+                fabobs.obs_record_span(
+                    "batcher.queue_wait", r.t_submit, t_launch,
+                    parent=r.parent,
+                )
+            launch_span = fabobs.span(
+                "batcher.launch",
+                parent=batch[0].parent if len(batch) == 1 else None,
+                lanes=len(keys), requests=len(batch),
+            )
+            if launch_span.span_id and len(batch) > 1:
+                launch_span.set(req_ids=[
+                    r.parent.attrs.get("req_id") for r in batch
+                    if r.parent is not None
+                ])
             try:
-                with fabobs.span(
-                    "batcher.launch", lanes=len(keys), requests=len(batch)
-                ):
+                with launch_span:
                     resolver = self._launch(keys, sigs, digests)
             except BaseException as exc:  # fablint: disable=broad-except  # error propagated to every waiting caller via r.error
                 for r in batch:
@@ -374,7 +398,9 @@ class VerifyBatcher:
             self.lanes += len(keys)
             fabobs.obs_count("fabric_batcher_launches_total", mode=self.mode)
             fabobs.obs_observe("fabric_batcher_batch_lanes", len(keys))
-            pending.append((batch, resolver, time.perf_counter(), len(keys)))
+            pending.append(
+                (batch, resolver, time.perf_counter(), len(keys), launch_span)
+            )
             # depth-4 pipeline: keep up to three launches in flight before
             # settling the oldest — where per-launch latency is high,
             # serializing launches costs more than coalescing
@@ -432,9 +458,10 @@ class VerifyBatcher:
         resolver: Callable,
         t0: float = 0.0,
         lanes: int = 0,
+        launch_span=None,
     ) -> None:
         try:
-            with fabobs.span("batcher.settle", lanes=lanes):
+            with fabobs.span("batcher.settle", parent=launch_span, lanes=lanes):
                 out = list(resolver())
             if t0:
                 self._observe_rtt(lanes, time.perf_counter() - t0)

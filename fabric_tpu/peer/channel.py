@@ -14,7 +14,7 @@ from __future__ import annotations
 
 from typing import Callable, Optional
 
-from fabric_tpu.common import flogging
+from fabric_tpu.common import fabobs, flogging
 from fabric_tpu.crypto.bccsp import Provider, default_provider
 from fabric_tpu.ledger.kvledger import KVLedger
 from fabric_tpu.msp.identity import MSPManager
@@ -92,11 +92,15 @@ class Channel:
         everything that may overlap the previous block's sequential
         MVCC/commit epilogue. Returns the opaque tuple store_block takes
         as `prepared`."""
-        self._verify_block_content(block)
-        parsed = parse_block(list(block.data.data))
-        jobs, job_identity, keys, sigs, digests = (
-            self.validator.collect_sig_jobs(parsed)
-        )
+        number = block.header.number
+        with fabobs.span("prepare.content_check", block=number):
+            self._verify_block_content(block)
+        with fabobs.span("prepare.parse", block=number):
+            parsed = parse_block(list(block.data.data))
+        with fabobs.span("prepare.collect_sig_jobs", block=number):
+            jobs, job_identity, keys, sigs, digests = (
+                self.validator.collect_sig_jobs(parsed)
+            )
         # dispatch WITHOUT waiting when the provider has an async seam
         # (device kernels, pool shards, the serve sidecar): the returned
         # resolver rides the prepared tuple and store_block collects the
@@ -128,37 +132,42 @@ class Channel:
         if prepared is None:
             prepared = self.prepare_block(block)
         parsed, jobs, job_identity, ok_list = prepared
+        number = block.header.number
         if callable(ok_list):
             # async-prepared tuple: resolve the verify dispatch now.  A
             # resolver failure raises here and surfaces through the
             # commit error path (the block is NOT committed — fail
             # closed), same as a synchronous batch_verify failure would.
-            ok_list = ok_list()
-        sig_results = self.validator.finish_sig_results(
-            jobs, job_identity, ok_list
-        )
-        flags = self.validator.validate(
-            block, parsed=parsed, sig_results=sig_results
-        )
-        t_validate = _time.perf_counter() - t0
-        rwsets = [p.rwset for p in parsed]
-        # materializing rwsets may demote txs the native walker accepted
-        # but the Python parser rejects (ParsedTx.rwset divergence guard);
-        # fold that into the filter BEFORE it is persisted so native and
-        # pure-Python peers commit the same TRANSACTIONS_FILTER
-        refilter = False
-        for p in parsed:
-            if p.code == TxValidationCode.BAD_RWSET and (
-                flags.flag(p.index) == TxValidationCode.VALID
-            ):
-                flags.set_flag(p.index, TxValidationCode.BAD_RWSET)
-                rwsets[p.index] = None
-                refilter = True
-        if refilter:
-            block.metadata.metadata[common_pb2.TRANSACTIONS_FILTER] = (
-                flags.tobytes()
+            with fabobs.span("commit.await_verdicts", block=number):
+                ok_list = ok_list()
+        with fabobs.span("commit.validate", block=number):
+            sig_results = self.validator.finish_sig_results(
+                jobs, job_identity, ok_list
             )
-        pvt_data, missing = self._assemble_pvt_data(block, parsed, flags)
+            flags = self.validator.validate(
+                block, parsed=parsed, sig_results=sig_results
+            )
+        t_validate = _time.perf_counter() - t0
+        with fabobs.span("commit.rwsets", block=number):
+            rwsets = [p.rwset for p in parsed]
+            # materializing rwsets may demote txs the native walker accepted
+            # but the Python parser rejects (ParsedTx.rwset divergence guard);
+            # fold that into the filter BEFORE it is persisted so native and
+            # pure-Python peers commit the same TRANSACTIONS_FILTER
+            refilter = False
+            for p in parsed:
+                if p.code == TxValidationCode.BAD_RWSET and (
+                    flags.flag(p.index) == TxValidationCode.VALID
+                ):
+                    flags.set_flag(p.index, TxValidationCode.BAD_RWSET)
+                    rwsets[p.index] = None
+                    refilter = True
+            if refilter:
+                block.metadata.metadata[common_pb2.TRANSACTIONS_FILTER] = (
+                    flags.tobytes()
+                )
+        with fabobs.span("commit.assemble_pvt", block=number):
+            pvt_data, missing = self._assemble_pvt_data(block, parsed, flags)
         result = self.ledger.commit(
             block, rwsets=rwsets, pvt_data=pvt_data, missing_pvt=missing
         )

@@ -17,22 +17,33 @@ from __future__ import annotations
 import queue
 import threading
 import time
-from typing import Callable, Dict, Optional
+from typing import Callable, Optional
 
 from fabric_tpu.common import fabobs
-from fabric_tpu.common.fabobs import STAGE_BUCKETS
 from fabric_tpu.common.faults import fault_point
 from fabric_tpu.common.flogging import must_get_logger
-from fabric_tpu.common.metrics import (
-    new_histogram_state,
-    observe_into,
-    summary_from_histogram_state,
-)
 from fabric_tpu.protos import common_pb2
 
 
 class PipelineError(Exception):
     pass
+
+
+class _Handoff:
+    """What crosses the queue: the block and its number, stage A's
+    result, stage A's span (the parent of everything stage B records for
+    the block) and the moment the put succeeded.  The submitter stamps
+    ``t_put`` after the put returns, so a committer that was already
+    waiting may read it unset: the block then waited for nobody."""
+
+    __slots__ = ("block", "number", "prepared", "span", "t_put")
+
+    def __init__(self, block, number, prepared, span):
+        self.block = block
+        self.number = number
+        self.prepared = prepared
+        self.span = span
+        self.t_put: Optional[float] = None
 
 
 class CommitPipeline:
@@ -58,14 +69,6 @@ class CommitPipeline:
         # without stop()) distinguish slow from dead
         self.last_error: Optional[BaseException] = None
         self._crashed = False
-        # per-stage latency as metrics-SPI histogram state (PR 10: the
-        # raw-sample reservoirs became bucket accumulators — one
-        # definition shared with /metrics, constant memory for the
-        # process lifetime, summarized by summary_from_histogram_state)
-        self._stage_hist = {
-            "prepare": new_histogram_state(STAGE_BUCKETS),
-            "commit": new_histogram_state(STAGE_BUCKETS),
-        }
         self._committer = threading.Thread(
             target=self._commit_loop,
             name=f"commit-{channel.channel_id}",
@@ -84,14 +87,17 @@ class CommitPipeline:
         with self._pending_lock:
             self._pending += 1
             self._idle.clear()
+        number = int(getattr(block.header, "number", 0))
         try:
             t0 = time.perf_counter()
-            with fabobs.span(
-                "pipeline.prepare",
-                block=int(getattr(block.header, "number", 0)),
-            ):
+            with fabobs.span("pipeline.prepare", block=number) as prep_span:
                 prepared = self.channel.prepare_block(block)
-            self._observe_stage("prepare", time.perf_counter() - t0)
+            t_offered = time.perf_counter()
+            fabobs.obs_observe(
+                "fabric_pipeline_stage_seconds", t_offered - t0,
+                stage="prepare",
+            )
+            item = _Handoff(block, number, prepared, prep_span)
             # bounded put that watches _stopped: a plain blocking put on
             # a full queue after stop() would wait forever — the
             # committer has exited and will never drain it (pipeline
@@ -100,9 +106,15 @@ class CommitPipeline:
                 if self._stopped.is_set():
                     raise PipelineError("pipeline stopped")
                 try:
-                    self._prepared.put((block, prepared), timeout=0.2)
+                    self._prepared.put(item, timeout=0.2)
                 except queue.Full:
                     continue
+                item.t_put = time.perf_counter()
+                # the submitter held by the full queue (P7 backpressure)
+                fabobs.obs_record_span(
+                    "pipeline.backpressure", t_offered, item.t_put,
+                    parent=prep_span, block=number,
+                )
                 if self._stopped.is_set() and not self._committer.is_alive():
                     # stop() landed between our check and the put: the
                     # committer will never consume this item. Reclaim it
@@ -140,21 +152,28 @@ class CommitPipeline:
                 item = self._prepared.get(timeout=0.2)
             except queue.Empty:
                 continue
-            block, prepared = item
+            t_taken = time.perf_counter()
+            block, number = item.block, item.number
+            # a prepared block waiting for the committer
+            fabobs.obs_record_span(
+                "pipeline.queue_wait", item.t_put or t_taken, t_taken,
+                parent=item.span, block=number,
+            )
             try:
                 # chaos seam: keyed by block number, so a seeded plan
                 # fails a deterministic subset of commits
-                fault_point(
-                    "pipeline.commit",
-                    key=int(getattr(block.header, "number", 0)),
-                )
+                fault_point("pipeline.commit", key=number)
                 t0 = time.perf_counter()
                 with fabobs.span(
-                    "pipeline.commit",
-                    block=int(getattr(block.header, "number", 0)),
+                    "pipeline.commit", parent=item.span, block=number
                 ):
-                    flags = self.channel.store_block(block, prepared=prepared)
-                self._observe_stage("commit", time.perf_counter() - t0)
+                    flags = self.channel.store_block(
+                        block, prepared=item.prepared
+                    )
+                fabobs.obs_observe(
+                    "fabric_pipeline_stage_seconds",
+                    time.perf_counter() - t0, stage="commit",
+                )
                 if self.on_commit is not None:
                     self.on_commit(block, flags)
             except Exception as exc:  # noqa: BLE001 - surfaced to the owner
@@ -177,27 +196,6 @@ class CommitPipeline:
                     self._pending -= 1
                     if self._pending == 0:
                         self._idle.set()
-
-    def _observe_stage(self, stage: str, seconds: float) -> None:
-        with self._pending_lock:
-            observe_into(self._stage_hist[stage], STAGE_BUCKETS, seconds)
-        fabobs.obs_observe(
-            "fabric_pipeline_stage_seconds", seconds, stage=stage
-        )
-
-    def stage_stats(self) -> Dict[str, Dict[str, float]]:
-        """Per-stage latency summary over the accumulated histogram
-        state: {"prepare": {n, p50_ms, p99_ms, mean_ms}, "commit":
-        {...}} — what 1907.08367's reordered-stage analysis wants
-        measured, served from the live pipeline instead of a one-off
-        bench probe.  Quantiles are bucket upper bounds (STAGE_BUCKETS),
-        the same series a /metrics scrape sees."""
-        with self._pending_lock:
-            states = {
-                k: summary_from_histogram_state(v, STAGE_BUCKETS)
-                for k, v in self._stage_hist.items()
-            }
-        return states
 
     def drain(self, timeout: float = 30.0) -> bool:
         """Wait until every submitted block has committed.  Returns

@@ -574,20 +574,26 @@ class SidecarProvider:
 
     # -- the remote verify loop -------------------------------------------
     def _verify_once(
-        self, payload: bytes, timeout_s: Optional[float] = None
+        self, payload: bytes, timeout_s: Optional[float], encode_span
     ) -> Tuple[int, int, Optional[List[bool]], str]:
-        token = self.client.submit(proto.OP_VERIFY, payload)
-        try:
-            return proto.decode_verify_response(
-                self.client.await_reply(token, timeout_s)
-            )
-        except SidecarUnavailable:
-            # abandoning the wait (budget/timeout) must TELL the
-            # server: an uncancelled tight-deadline batch would make
-            # the slow sidecar compute a verdict nobody will read —
-            # exactly the capacity OP_CANCEL exists to reclaim
-            self.client.cancel(token)
-            raise
+        """One request on the wire.  Its id is the connection's token,
+        known only once the frame is out, so the spans that opened before
+        that (``encode_span``, ``client.roundtrip``) are given it late."""
+        with fabobs.span("client.roundtrip") as roundtrip_span:
+            token = self.client.submit(proto.OP_VERIFY, payload)
+            roundtrip_span.set(req_id=token)
+            encode_span.set(req_id=token)
+            try:
+                reply = self.client.await_reply(token, timeout_s)
+            except SidecarUnavailable:
+                # abandoning the wait (budget/timeout) must TELL the
+                # server: an uncancelled tight-deadline batch would make
+                # the slow sidecar compute a verdict nobody will read —
+                # exactly the capacity OP_CANCEL exists to reclaim
+                self.client.cancel(token)
+                raise
+        with fabobs.span("client.decode", req_id=token):
+            return proto.decode_verify_response(reply)
 
     def batch_verify(
         self, keys, signatures, digests
@@ -630,9 +636,10 @@ class SidecarProvider:
                             keys, signatures, digests,
                             "deadline expired during connect",
                         )
-                payload = self._encode(keys, signatures, digests, remaining)  # fabdet: disable=wallclock-in-det  # remaining-budget recompute before re-encode: the deadline_ms wire field is semantically time-derived by contract (masks are the det surface)
+                with fabobs.span("client.encode") as encode_span:
+                    payload = self._encode(keys, signatures, digests, remaining)  # fabdet: disable=wallclock-in-det  # remaining-budget recompute before re-encode: the deadline_ms wire field is semantically time-derived by contract (masks are the det surface)
                 status, retry_ms, mask, message = self._verify_once(
-                    payload, remaining
+                    payload, remaining, encode_span
                 )
             except (SidecarUnavailable, proto.ProtocolError) as exc:
                 if deadline is not None and time.monotonic() >= deadline:

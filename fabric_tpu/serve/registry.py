@@ -42,6 +42,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from fabric_tpu.common import fabobs
 from fabric_tpu.common.flogging import must_get_logger
 
 logger = must_get_logger("serve.registry")
@@ -77,6 +78,11 @@ _AOT_SEQ = iter(range(1, 1 << 30))
 _AOT_COMPILE_LOCK = threading.Lock()
 
 
+#: JAX durations shorter than this leave no ``program.*`` span: a process
+#: traces hundreds of small jits (``jnp`` helpers), and set-up is minutes.
+PROGRAM_SPAN_MIN_S = 0.1
+
+
 class _CompileCounters:
     """Process-wide jax compile/cache-event accounting.
 
@@ -84,7 +90,15 @@ class _CompileCounters:
     readers snapshot-and-diff.  JAX 0.9 times ``compile_or_get_cached``
     as a whole under ``backend_compile_duration``, so that event fires
     for a persistent-cache HIT too (with the retrieval time): a real
-    XLA compile is a compile request that was not a hit."""
+    XLA compile is a compile request that was not a hit.
+
+    The same listener puts a process's set-up on the fabobs flight ring:
+    each of JAX's own durations of :data:`PROGRAM_SPAN_MIN_S` or more
+    becomes a span that ends now and began ``duration`` ago,
+    ``program.trace_lower`` (``jaxpr_trace``, ``jaxpr_to_mlir_module``)
+    or ``program.compile_or_load`` (``backend_compile``: a cold compile
+    or a cache load).  A jit traced inside another's trace nests, so a
+    reader takes the union of the spans, not their sum."""
 
     _lock = threading.Lock()
     _installed = False
@@ -106,6 +120,18 @@ class _CompileCounters:
         def _on_duration(event: str, duration: float, **kwargs) -> None:
             if "backend_compile" in event:
                 cls.requests += 1  # GIL-atomic int add, monotonic counter
+                name = "program.compile_or_load"
+            elif "jaxpr_trace" in event or "jaxpr_to_mlir_module" in event:
+                name = "program.trace_lower"
+            else:
+                return
+            if duration >= PROGRAM_SPAN_MIN_S:
+                now = time.perf_counter()
+                fabobs.obs_record_span(
+                    name, now - duration, now,
+                    event=event.rsplit("/", 1)[-1],
+                    fun=kwargs.get("fun_name"),
+                )
 
         jax.monitoring.register_event_listener(_on_event)
         jax.monitoring.register_event_duration_secs_listener(_on_duration)

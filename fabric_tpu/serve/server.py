@@ -777,12 +777,19 @@ class SidecarServer:
             # permits release — one-shot so the failure-path release in
             # the finally block can never double-free
             release_qos = self._qos_release_once(qos_class, len(keys))
+            # made here and entered once admitted: the batcher's own spans
+            # for this request link to it across the dispatcher thread
+            verify_span = fabobs.span(
+                "serve.verify", req_id=req_id, lanes=len(keys),
+                cls=proto.qos_name(qos_class), channel=channel,
+            )
             resolver = self.batcher.try_submit(
                 keys, sigs, digests, on_dispatch=release_qos,
                 deadline_s=(
                     time.monotonic() + deadline_ms / 1000.0
                     if deadline_ms > 0 else None
                 ),
+                parent=verify_span,
             )
             if resolver is None:
                 self.stats.reject(qos_class)
@@ -792,10 +799,7 @@ class SidecarServer:
                     send_lock=send_lock, version=version,
                 )
                 return
-            with fabobs.span(
-                "serve.verify", req_id=req_id, lanes=len(keys),
-                cls=proto.qos_name(qos_class), channel=channel,
-            ):
+            with verify_span:
                 mask = resolver()
             if self._stopping:
                 # the batcher may have settled this request fail-closed
@@ -832,11 +836,12 @@ class SidecarServer:
             self.stats.record(
                 len(mask), bucket, time.perf_counter() - t0, qos_class
             )
-            self._send(
-                conn, proto.OP_VERIFY, req_id,
-                proto.encode_verify_response(proto.ST_OK, mask=mask),
-                send_lock, version=version,
-            )
+            with fabobs.span("serve.reply", req_id=req_id):
+                self._send(
+                    conn, proto.OP_VERIFY, req_id,
+                    proto.encode_verify_response(proto.ST_OK, mask=mask),
+                    send_lock, version=version,
+                )
         except Exception as exc:  # noqa: BLE001 - per-request fail-closed
             # includes a payload-level ProtocolError: recv_frame already
             # consumed the whole length-prefixed frame, so the stream is

@@ -392,7 +392,10 @@ def test_obs_cannot_alter_mask():
     assert reg.dropped > 0
 
 
-def test_pipeline_stage_stats_and_series():
+def test_pipeline_stage_series_and_spans():
+    """The /metrics series counts every block of each stage, and the
+    flight ring holds one span per block and stage (the pipeline's own
+    per-stage histogram is gone: bucket bounds sold as p50/p99)."""
     from fabric_tpu.peer.pipeline import CommitPipeline
     from fabric_tpu.protos import common_pb2
 
@@ -415,13 +418,17 @@ def test_pipeline_stage_stats_and_series():
             assert p.drain(5.0)
         finally:
             p.stop()
-        stats = p.stage_stats()
-        assert stats["prepare"]["n"] == 3
-        assert stats["commit"]["n"] == 3
-        assert stats["commit"]["p50_ms"] >= 0
+        assert not hasattr(p, "stage_stats")
         text = reg.render()
         assert 'fabric_pipeline_stage_seconds_count{stage="prepare"} 3' in text
         assert 'fabric_pipeline_stage_seconds_count{stage="commit"} 3' in text
+        for stage in ("pipeline.prepare", "pipeline.commit",
+                      "pipeline.backpressure", "pipeline.queue_wait"):
+            blocks = sorted(
+                e["args"]["block"] for e in reg.trace_events()
+                if e["name"] == stage
+            )
+            assert blocks == [0, 1, 2], stage
 
 
 def test_fault_fires_counted():
@@ -542,3 +549,493 @@ def test_sidecar_ops_mount_metrics_and_healthz(tmp_path):
             assert "batcher" in failed
         finally:
             server.stop()
+
+
+# ---------------- spans: the mechanism (PR 28) ----------------
+
+
+def _spans(reg, name=None):
+    return [
+        e for e in reg.trace_events()
+        if e.get("ph") == "X" and (name is None or e["name"] == name)
+    ]
+
+
+def test_record_span_keeps_the_given_start():
+    with obs_installed() as reg:
+        t0 = time.perf_counter() - 0.25  # a wait that began in the past
+        with fabobs.span("outer", block=7) as outer:
+            fabobs.obs_record_span("waited", t0, t0 + 0.125, lanes=3)
+        fabobs.obs_record_span("handed_over", t0, t0 + 0.5, parent=outer)
+        waited, = _spans(reg, "waited")
+        handed, = _spans(reg, "handed_over")
+        outer_ev, = _spans(reg, "outer")
+        assert waited["dur"] == pytest.approx(125000.0, abs=0.2)
+        assert handed["dur"] == pytest.approx(500000.0, abs=0.2)
+        # the given start, not the moment of recording: before its parent
+        assert waited["ts"] == handed["ts"] < outer_ev["ts"]
+        # parent: the thread's open span, or the one handed over
+        assert waited["args"]["parent_id"] == outer.span_id
+        assert handed["args"]["parent_id"] == outer.span_id
+        assert waited["args"]["block"] == handed["args"]["block"] == 7
+        assert waited["args"]["lanes"] == 3
+        assert len({e["args"]["span_id"] for e in _spans(reg)}) == 3
+
+
+def test_record_span_disabled_is_noop_and_failures_swallowed():
+    fabobs.obs_record_span("nobody", 0.0, 1.0)  # disabled: nothing
+    with obs_installed() as reg:
+        fabobs.obs_record_span("bad", "not a time", 1.0)
+        assert reg.dropped == 1 and _spans(reg) == []
+
+
+def test_child_inherits_block_and_request_ids():
+    with obs_installed() as reg:
+        with fabobs.span("block.stage", block=0):
+            with fabobs.span("inner", lanes=4):
+                with fabobs.span("innermost"):
+                    pass
+            with fabobs.span("own", block=9):
+                pass
+        with fabobs.span("request", req_id=11, req_ids=[11, 12]):
+            with fabobs.span("req.inner"):
+                pass
+        args = {e["name"]: e["args"] for e in _spans(reg)}
+        assert args["inner"]["block"] == args["innermost"]["block"] == 0
+        assert args["own"]["block"] == 9
+        assert args["req.inner"]["req_id"] == 11
+        assert args["req.inner"]["req_ids"] == [11, 12]
+        assert "lanes" not in args["innermost"]  # identifiers only
+
+
+def test_span_set_lands_in_the_ring_even_after_exit():
+    with obs_installed() as reg:
+        with fabobs.span("encode") as enc:
+            pass
+        with fabobs.span("roundtrip") as rt:
+            rt.set(req_id=5)
+        enc.set(req_id=5)  # the id was allocated after `encode` closed
+        assert [e["args"]["req_id"] for e in _spans(reg)] == [5, 5]
+        snapshot = reg.trace_events()
+        enc.set(req_id=6)  # a snapshot taken earlier does not move
+        assert snapshot[0]["args"]["req_id"] == 5
+    fabobs.span("disabled").set(req_id=1)  # the shared no-op span
+
+
+def test_span_ids_unique_across_threads():
+    with obs_installed(ring=1 << 14) as reg:
+        def work():
+            for _ in range(500):
+                with fabobs.span("t"):
+                    pass
+
+        threads = [threading.Thread(target=work) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+        ids = [e["args"]["span_id"] for e in _spans(reg, "t")]
+        assert len(ids) == len(set(ids)) == 4000
+
+
+class _RecordingAnnotation:
+    """Stand-in for jax.profiler.TraceAnnotation."""
+
+    log = []
+
+    def __init__(self, name, **kwargs):
+        assert not kwargs, "name only: attributes stay in the ring"
+        self.name = name
+
+    def __enter__(self):
+        type(self).log.append(("enter", self.name, threading.get_ident()))
+        return self
+
+    def __exit__(self, *exc):
+        type(self).log.append(("exit", self.name, threading.get_ident()))
+
+
+def test_every_span_is_one_annotation_of_its_name_on_its_thread(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(_RecordingAnnotation, "log", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _RecordingAnnotation)
+    with obs_installed() as reg:
+        def other():
+            with fabobs.span("on.other.thread"):
+                pass
+
+        with fabobs.span("outer", block=1):
+            with fabobs.span("inner"):
+                pass
+            t = threading.Thread(target=other)
+            t.start()
+            t.join(10)
+            fabobs.obs_record_span("past", 0.0, 1.0)  # nobody executes it
+        log = _RecordingAnnotation.log
+        tid_of = {e["name"]: e["tid"] for e in _spans(reg)}
+        for name in ("outer", "inner", "on.other.thread"):
+            assert log.count(("enter", name, tid_of[name])) == 1
+            assert log.count(("exit", name, tid_of[name])) == 1
+        assert tid_of["on.other.thread"] != tid_of["outer"]
+        assert len(log) == 6 and not any(n == "past" for _, n, _ in log)
+        # properly nested on the thread: inner closes before outer
+        mine = [(k, n) for k, n, tid in log if tid == tid_of["outer"]]
+        assert mine == [("enter", "outer"), ("enter", "inner"),
+                        ("exit", "inner"), ("exit", "outer")]
+
+
+@pytest.mark.parametrize("fails_in", ["__init__", "__enter__", "__exit__"])
+def test_raising_annotation_loses_no_ring_record(monkeypatch, fails_in):
+    import jax
+
+    class _Exploding:
+        def __init__(self, name):
+            if fails_in == "__init__":
+                raise RuntimeError("profiler exploded")
+
+        def __enter__(self):
+            if fails_in == "__enter__":
+                raise RuntimeError("profiler exploded")
+            return self
+
+        def __exit__(self, *exc):
+            if fails_in == "__exit__":
+                raise RuntimeError("profiler exploded")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Exploding)
+    with obs_installed() as reg:
+        with pytest.raises(KeyError):  # the wrapped code's own error
+            with fabobs.span("guarded", block=3):
+                with fabobs.span("child"):
+                    pass
+                raise KeyError("from the wrapped code")
+        guarded, = _spans(reg, "guarded")
+        child, = _spans(reg, "child")
+        assert guarded["args"]["error"] == "KeyError"
+        assert child["args"]["parent_id"] == guarded["args"]["span_id"]
+        assert reg.dropped == 2  # one per span, swallowed
+        assert fabobs.current_span() is None
+
+
+def test_span_records_with_jax_absent(monkeypatch):
+    import sys
+
+    monkeypatch.delitem(sys.modules, "jax")
+    with obs_installed() as reg:
+        with fabobs.span("no.jax", block=2):
+            pass
+        ev, = _spans(reg, "no.jax")
+        assert ev["args"]["block"] == 2 and reg.dropped == 0
+
+
+def test_fabobs_imports_no_jax():
+    import ast
+    import inspect
+
+    tree = ast.parse(inspect.getsource(fabobs))
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported |= {a.name.split(".")[0] for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            imported.add((node.module or "").split(".")[0])
+    assert "jax" not in imported
+
+
+# ---------------- spans: the sites (PR 28) ----------------
+
+
+class _PlantedKernel:
+    """Stands where TPUProvider keeps ops.p256_kernel: no trace, no
+    compile.  The verdict is the host precheck mask (DER ok, low-S, key
+    on the curve), which is the right verdict for well-formed lanes."""
+
+    class _Jitted:
+        """What the provider uses of a jitted function: the call, and
+        ``lower`` (made once per shape, before the first call)."""
+
+        def __call__(self, *operands):
+            return operands[-1]  # `ok`
+
+        def lower(self, *operands):
+            return None
+
+    verify_batch_bytes_jit = _Jitted()
+    verify_batch_jit = _Jitted()
+
+
+def _planted_tpu_provider():
+    from fabric_tpu.crypto.tpu_provider import TPUProvider
+
+    provider = TPUProvider()
+    provider._pk = _PlantedKernel()
+    return provider
+
+
+BLOCK_SPANS = (
+    "pipeline.prepare", "prepare.content_check", "prepare.parse",
+    "prepare.collect_sig_jobs", "tpu.prep", "tpu.dispatch",
+    "pipeline.backpressure", "pipeline.queue_wait", "pipeline.commit",
+    "commit.await_verdicts", "tpu.resolve", "commit.validate",
+    "commit.rwsets", "commit.assemble_pvt", "ledger.mvcc", "ledger.block_append", "ledger.state_commit",
+)
+
+
+def test_one_block_through_the_pipeline_yields_each_span_once(tmp_path):
+    pytest.importorskip("cryptography")
+    import test_pipeline as tp
+
+    from fabric_tpu.peer.channel import Channel
+    from fabric_tpu.peer.pipeline import CommitPipeline
+
+    org_world = tp.world.__wrapped__()
+    provider = _planted_tpu_provider()
+    ch = Channel(
+        tp.CHANNEL, str(tmp_path), org_world["mgr"], org_world["registry"],
+        provider,
+    )
+    blocks = tp._chain(org_world, 2)
+    with obs_installed() as reg:
+        pipe = CommitPipeline(ch)
+        try:
+            for b in blocks:
+                pipe.submit(b)
+            assert pipe.drain(timeout=60)
+        finally:
+            pipe.stop()
+        assert pipe.last_error is None and ch.ledger.height == 2
+        events = _spans(reg)
+
+    for number in (0, 1):
+        mine = {}
+        for e in events:
+            if e["args"].get("block") == number:
+                assert e["name"] not in mine, f"{e['name']} twice"
+                mine[e["name"]] = e
+        assert sorted(mine) == sorted(BLOCK_SPANS)
+        ident = {n: e["args"]["span_id"] for n, e in mine.items()}
+        parent = {n: e["args"].get("parent_id", 0) for n, e in mine.items()}
+        # stage A, on the submitting thread
+        assert parent["pipeline.prepare"] == 0
+        for child in ("prepare.content_check", "prepare.parse",
+                      "prepare.collect_sig_jobs", "tpu.prep", "tpu.dispatch"):
+            assert parent[child] == ident["pipeline.prepare"], child
+        # across the queue: the hand-off carries stage A's span
+        for child in ("pipeline.backpressure", "pipeline.queue_wait",
+                      "pipeline.commit"):
+            assert parent[child] == ident["pipeline.prepare"], child
+        assert mine["pipeline.commit"]["tid"] != mine["pipeline.prepare"]["tid"]
+        # stage B, on the committer thread
+        for child in ("commit.await_verdicts", "commit.validate",
+                      "commit.rwsets", "commit.assemble_pvt",
+                      "ledger.mvcc", "ledger.block_append",
+                      "ledger.state_commit"):
+            assert parent[child] == ident["pipeline.commit"], child
+            assert mine[child]["tid"] == mine["pipeline.commit"]["tid"]
+        assert parent["tpu.resolve"] == ident["commit.await_verdicts"]
+        assert mine["tpu.prep"]["args"]["lanes"] == 6
+        assert mine["tpu.prep"]["args"]["distinct_keys"] == 2
+        assert mine["tpu.dispatch"]["args"]["bucket"] == 128
+        # self time = duration - children: never negative, and what the
+        # ledger's own clock reads split is inside the stage
+        commit = mine["pipeline.commit"]
+        children = sum(
+            e["dur"] for n, e in mine.items()
+            if parent[n] == ident["pipeline.commit"]
+        )
+        assert 0 <= commit["dur"] - children < commit["dur"]
+        # the hand-off's waits lie between the two stages
+        prep_end = mine["pipeline.prepare"]["ts"] + mine["pipeline.prepare"]["dur"]
+        assert mine["pipeline.backpressure"]["ts"] >= prep_end - 1
+        wait = mine["pipeline.queue_wait"]
+        assert wait["ts"] + wait["dur"] <= commit["ts"] + 1
+
+
+def test_device_rung_seconds_leave_out_the_wait_to_be_resolved():
+    """fabric_verify_seconds{rung="device"} is the provider's own prep +
+    dispatch + resolve, not dispatch -> consumed."""
+    import hashlib
+
+    from fabric_tpu.common import der
+    from fabric_tpu.crypto import hostec
+    from fabric_tpu.crypto.bccsp import ECDSAPublicKey
+
+    d = 0xD1CE
+    pub = ECDSAPublicKey(*hostec.scalar_base_mult(d))
+    digest = hashlib.sha256(b"device rung").digest()
+    sig = der.marshal_signature(*hostec.sign_digest(d, digest))
+    provider = _planted_tpu_provider()
+    with obs_installed() as reg:
+        resolve = provider.batch_verify_async([pub] * 4, [sig] * 4, [digest] * 4)
+        time.sleep(0.3)  # the resolver waits to be called
+        assert resolve() == [True] * 4
+        series = reg.snapshot()["fabric_verify_seconds"]["series"]
+        assert series["rung=device"]["n"] == 1
+        assert series["rung=device"]["mean_ms"] < 250
+        own = sum(
+            e["dur"] for e in _spans(reg)
+            if e["name"] in ("tpu.prep", "tpu.dispatch", "tpu.resolve")
+        )
+        assert own < 250e3
+
+
+REQUEST_SPANS = (
+    "client.encode", "client.roundtrip", "client.decode", "serve.decode",
+    "serve.verify", "serve.reply", "batcher.queue_wait", "batcher.launch",
+    "tpu.prep", "tpu.dispatch", "batcher.settle", "tpu.resolve",
+)
+
+
+def test_one_sidecar_request_yields_its_spans_under_one_req_id(tmp_path):
+    import hashlib
+
+    from fabric_tpu.common import der
+    from fabric_tpu.crypto import hostec
+    from fabric_tpu.crypto.bccsp import ECDSAPublicKey
+    from fabric_tpu.serve.client import SidecarProvider
+    from fabric_tpu.serve.server import SidecarServer
+
+    d = 0xC0DE
+    pub = ECDSAPublicKey(*hostec.scalar_base_mult(d))
+    digest = hashlib.sha256(b"one request").digest()
+    sig = der.marshal_signature(*hostec.sign_digest(d, digest))
+    with obs_installed() as reg:
+        server = SidecarServer(
+            str(tmp_path / "spans.sock"), engine="device",
+            provider=_planted_tpu_provider(), warm_ladder="off",
+        )
+        try:
+            client = SidecarProvider(address=server.start())
+            try:
+                for _ in range(2):
+                    mask = client.batch_verify([pub] * 5, [sig] * 5, [digest] * 5)
+                    assert mask == [True] * 5
+                assert client.degraded is False
+            finally:
+                client.client.close()
+        finally:
+            server.stop()
+        events = _spans(reg)
+
+    req_ids = sorted({e["args"]["req_id"] for e in events
+                      if e["name"] == "client.roundtrip"})
+    assert len(req_ids) == 2
+    for req_id in req_ids:
+        mine = {}
+        for e in events:
+            if e["args"].get("req_id") == req_id:
+                assert e["name"] not in mine, f"{e['name']} twice"
+                mine[e["name"]] = e
+        assert sorted(mine) == sorted(REQUEST_SPANS)
+        ident = {n: e["args"]["span_id"] for n, e in mine.items()}
+        parent = {n: e["args"].get("parent_id", 0) for n, e in mine.items()}
+        # the batcher's spans, on the dispatcher thread, hang under the
+        # request's serve.verify; the provider's under the batcher's
+        assert parent["batcher.queue_wait"] == ident["serve.verify"]
+        assert parent["batcher.launch"] == ident["serve.verify"]
+        assert parent["batcher.settle"] == ident["batcher.launch"]
+        assert parent["tpu.prep"] == parent["tpu.dispatch"] == ident["batcher.launch"]
+        assert parent["tpu.resolve"] == ident["batcher.settle"]
+        assert mine["batcher.launch"]["tid"] != mine["serve.verify"]["tid"]
+        assert mine["batcher.launch"]["args"]["requests"] == 1
+        # the wait began when the request was admitted, before its launch
+        wait = mine["batcher.queue_wait"]
+        launch = mine["batcher.launch"]
+        assert wait["ts"] + wait["dur"] <= launch["ts"] + 1
+        # the client's round trip holds the server's whole handling
+        rt = mine["client.roundtrip"]
+        for name in ("serve.decode", "serve.verify"):
+            assert rt["ts"] <= mine[name]["ts"]
+            assert mine[name]["ts"] + mine[name]["dur"] <= rt["ts"] + rt["dur"]
+
+
+def test_coalesced_launch_carries_req_ids():
+    from fabric_tpu.parallel.batcher import VerifyBatcher
+
+    class _Sync:
+        def batch_verify(self, keys, sigs, digests):
+            return [True] * len(keys)
+
+    gate = threading.Event()
+
+    class _Gated(_Sync):
+        def batch_verify(self, keys, sigs, digests):
+            gate.wait(10)
+            return super().batch_verify(keys, sigs, digests)
+
+    with obs_installed() as reg:
+        batcher = VerifyBatcher(_Gated(), linger_s=0.0)
+        try:
+            # the first launch holds the dispatcher; the next two requests
+            # queue up behind it and leave as one launch
+            first = batcher.submit([1], [b"s"], [b"d"])
+            time.sleep(0.05)
+            resolvers = []
+            for req_id in (21, 22):
+                with fabobs.span("serve.verify", req_id=req_id) as parent:
+                    resolvers.append(batcher.try_submit(
+                        [1, 2], [b"s"] * 2, [b"d"] * 2, parent=parent
+                    ))
+            gate.set()
+            assert first() == [True]
+            assert [r() for r in resolvers] == [[True, True]] * 2
+        finally:
+            gate.set()
+            batcher.stop()
+        launches = _spans(reg, "batcher.launch")
+        assert [e["args"]["requests"] for e in launches] == [1, 2]
+        assert launches[1]["args"]["req_ids"] == [21, 22]
+        assert "req_id" not in launches[1]["args"]
+        waits = _spans(reg, "batcher.queue_wait")
+        assert sorted(e["args"].get("req_id", 0) for e in waits) == [0, 21, 22]
+        settle = _spans(reg, "batcher.settle")[1]
+        assert settle["args"]["req_ids"] == [21, 22]
+
+
+@pytest.mark.parametrize("event, name", [
+    ("/jax/core/compile/jaxpr_trace_duration", "program.trace_lower"),
+    ("/jax/core/compile/jaxpr_to_mlir_module_duration", "program.trace_lower"),
+    ("/jax/core/compile/backend_compile_duration", "program.compile_or_load"),
+])
+def test_jax_duration_event_becomes_a_program_span(event, name):
+    """JAX's own durations of set-up, through the one listener the serve
+    registry installs: a span that ends now and began `duration` ago."""
+    import jax
+
+    from fabric_tpu.serve.registry import _CompileCounters
+
+    _CompileCounters.install()
+    with fabobs.obs_installed() as reg:
+        t_before = time.perf_counter()
+        jax.monitoring.record_event_duration_secs(event, 0.5, fun_name="f")
+        (span,) = _spans(reg, name)
+        assert span["dur"] == pytest.approx(0.5e6, abs=1.0)
+        end_us = span["ts"] + span["dur"]
+        assert end_us >= reg._us(t_before)
+        assert end_us <= reg._us(time.perf_counter())
+        assert span["args"]["event"] == event.rsplit("/", 1)[-1]
+        assert span["args"]["fun"] == "f"
+        # a jnp helper's few milliseconds leave no span
+        jax.monitoring.record_event_duration_secs(event, 0.05, fun_name="g")
+        assert len(_spans(reg, name)) == 1
+
+
+def test_other_jax_durations_and_a_disabled_fabobs_leave_no_program_span():
+    import jax
+
+    from fabric_tpu.serve.registry import _CompileCounters
+
+    _CompileCounters.install()
+    c0, h0 = _CompileCounters.snapshot()
+    # fabobs off: the counter still counts, nothing is recorded or raised
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.5
+    )
+    assert _CompileCounters.snapshot() == (c0 + 1, h0)
+    with fabobs.obs_installed() as reg:
+        jax.monitoring.record_event_duration_secs(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 9.0
+        )
+        assert [e for e in reg.trace_events()
+                if e["name"].startswith("program.")] == []

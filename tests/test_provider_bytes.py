@@ -153,3 +153,85 @@ def test_no_key_lane_is_dead_in_device_prep():
     assert list(prep[-1]) == [True, False, True]
     *_, ok = prov.prep_limbs([None, key], [sig] * 2, [dig] * 2)
     assert list(ok) == [False, True]
+
+
+# ---- the first dispatch of a shape runs on a fresh stack (PR 28) ----
+
+
+def _python_depth():
+    import sys
+
+    depth, frame = 0, sys._getframe()
+    while frame is not None:
+        depth, frame = depth + 1, frame.f_back
+    return depth
+
+
+def test_on_fresh_stack_runs_shallow_on_another_thread_and_hands_back():
+    import threading
+
+    from fabric_tpu.crypto.tpu_provider import _on_fresh_stack
+
+    def deep(n):
+        if n:
+            return deep(n - 1)
+        return _on_fresh_stack(
+            lambda a, b: (a + b, _python_depth(), threading.get_ident()), 2, 3
+        )
+
+    total, depth, ident = deep(40)
+    assert total == 5
+    assert ident != threading.get_ident()
+    # thread bootstrap, Thread.run, the wrapper, the lambda, this helper: a
+    # handful of frames whatever the caller's 40
+    assert depth < 10
+
+
+@pytest.mark.parametrize("exc", [ValueError("lowering refused"), KeyboardInterrupt()])
+def test_on_fresh_stack_raises_what_the_call_raised(exc):
+    from fabric_tpu.crypto.tpu_provider import _on_fresh_stack
+
+    def boom():
+        raise exc
+
+    with pytest.raises(type(exc)):
+        _on_fresh_stack(boom)
+
+
+def test_a_shape_is_lowered_once_on_another_thread_and_called_on_the_callers():
+    import threading
+    import types
+
+    from fabric_tpu.crypto import tpu_provider
+
+    provider = types.SimpleNamespace(_lowered=set())
+    dispatch = tpu_provider.TPUProvider._lower_on_fresh_stack_then_call
+    me = threading.get_ident()
+    lowered, called = [], []
+
+    class Program:  # what the provider uses of a jitted function
+        def lower(self, x):
+            lowered.append((x, threading.get_ident()))
+            if x < 0:
+                raise RuntimeError("lowering refused")
+
+        def __call__(self, x):
+            called.append((x, threading.get_ident()))
+            return x * 2
+
+    program = Program()
+    assert dispatch(provider, program, ("bytes", 2048), 21) == 42
+    assert dispatch(provider, program, ("bytes", 2048), 4) == 8
+    # lowered once, for the first arguments, elsewhere; both calls here
+    assert [x for x, _ in lowered] == [21] and lowered[0][1] != me
+    assert called == [(21, me), (4, me)]
+    # another shape is another program to trace and lower
+    assert dispatch(provider, program, ("bytes", 4096), 1) == 2
+    assert [x for x, _ in lowered] == [21, 1]
+    # a lowering that failed raises here, lowered nothing, and is tried again
+    with pytest.raises(RuntimeError):
+        dispatch(provider, program, ("limbs", 128), -1)
+    assert ("limbs", 128) not in provider._lowered
+    assert dispatch(provider, program, ("limbs", 128), 3) == 6
+    assert [x for x, _ in lowered] == [21, 1, -1, 3]
+    assert all(ident != me for _, ident in lowered)
