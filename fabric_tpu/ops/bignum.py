@@ -232,9 +232,14 @@ def reduce_canonical_l(ctx: MontCtx, xs: Sequence[jax.Array], times: int) -> Lis
 # - looped: lax.fori_loop whose body is ~10 vector ops on stacked
 #   (NLIMBS, B) arrays. XLA:CPU compiles the full ECDSA verify kernel
 #   in seconds instead of >10 minutes, the TPU compiler in minutes
-#   instead of not at all in useful time. The stacked layout
-#   (dynamic-index breaks fusion) may cost run time; not measured on
-#   this chip (ROADMAP D3).
+#   instead of not at all in useful time. Compiled for a v5e the loop is
+#   3-4 fusions, a pad and the counter a step, accumulator and operands in
+#   the on-chip vector memory (`S(1)`), the same program text at 2,048,
+#   4,096 and 6 x 4,096 lanes. On the chip (PR 31) a step over 6 x 2,048
+#   lanes costs what a step over 2,048 does (~1.4 us: the fixed cost of
+#   issuing its ops, not their width) and twice that over 6 x 4,096, so
+#   callers hand independent products to one call
+#   (fieldops.Field.mul_many) rather than loop once per product.
 # ---------------------------------------------------------------------------
 
 

@@ -120,6 +120,32 @@ def test_montgomery_multiply_compiles_in_the_chip_form(
     assert has_loop == (not bn._AUTO_CIOS_UNROLLED["tpu"])
 
 
+def test_stacked_multiply_compiles_as_one_loop(
+    one_chip, no_persistent_cache, as_on_tpu
+):
+    """Six products side by side (a level of a point operation) at the real
+    width: one looped CIOS over (20, 6, 4096), not six."""
+    k = 6
+    rows = jax.ShapeDtypeStruct(
+        (k, bn.NLIMBS, LANES), jnp.uint32, sharding=one_chip
+    )
+
+    def mul6(a, b):
+        pairs = [(pk.fe(bn.split(a[i])), pk.fe(bn.split(b[i]))) for i in range(k)]
+        return jnp.stack([bn.restack(x.limbs) for x in pk.FIELD.mul_many(pairs)])
+
+    compiled = _compile(mul6, rows, rows)
+    text = compiled.as_text()
+    assert _output_bytes(compiled) >= k * LIMBS_BYTES
+    if bn._AUTO_CIOS_UNROLLED["tpu"]:
+        assert "while" not in text
+    else:
+        # one `while` instruction, and its accumulator carries all six
+        loops = [l for l in text.splitlines() if " while(" in l]
+        assert len(loops) == 1, len(loops)
+        assert f"u32[{bn.NLIMBS},{k},{LANES}]" in loops[0]
+
+
 @pytest.mark.parametrize("op", ["point_add", "point_double"])
 def test_point_ops_compile(one_chip, no_persistent_cache, as_on_tpu, op):
     if op == "point_add":

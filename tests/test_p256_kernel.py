@@ -3,6 +3,7 @@
 import hashlib
 import secrets
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -76,6 +77,176 @@ class TestPointOps:
         got = read_affine(pk.point_double(make_point_batch(pts)))
         want = [p256.point_add(a, a) for a in pts]
         assert got == want
+
+
+def _random_fe(lanes, bound=1, seed=0):
+    """`lanes` canonical-limb values below bound * p, as an FE of that
+    bound (the worst the bound allows among them)."""
+    rng = np.random.default_rng(seed)
+    vals = [bound * p256.P - 1] + [
+        int.from_bytes(rng.bytes(40), "big") % (bound * p256.P)
+        for _ in range(lanes - 1)
+    ]
+    return pk.FE(tuple(jnp.asarray(bn.ints_to_limbs(vals))), bound)
+
+
+def _limbs_of(fe_):
+    return np.asarray(bn.restack(fe_.limbs))
+
+
+class TestMulMany:
+    """`Field.mul_many`: k independent products through one looped-CIOS
+    call, each limb for limb what `mul` gives for that pair."""
+
+    @pytest.mark.parametrize("k", [1, 2, 5, 6])
+    @pytest.mark.parametrize("kind", ["plain", "constant", "bound4x4"])
+    def test_matches_k_separate_muls(self, k, kind):
+        lanes = 8
+        if kind == "bound4x4":
+            pairs = [
+                (_random_fe(lanes, 4, seed=2 * i), _random_fe(lanes, 4, seed=2 * i + 1))
+                for i in range(k)
+            ]
+        else:
+            pairs = [
+                (_random_fe(lanes, 1 + i % 2, seed=2 * i), _random_fe(lanes, 1, seed=2 * i + 1))
+                for i in range(k)
+            ]
+        if kind == "constant":
+            # the curve's b, scalar limbs: on the left of every other pair
+            pairs = [(pk._B_FE, b) if i % 2 == 0 else (a, b) for i, (a, b) in enumerate(pairs)]
+        got = pk.FIELD.mul_many(pairs)
+        assert len(got) == k
+        for (a, b), g in zip(pairs, got):
+            want = pk.FIELD.mul(a, b)
+            assert g.bound == want.bound == 1
+            assert np.array_equal(_limbs_of(g), _limbs_of(want))
+
+    def test_all_constant_side(self):
+        # level 2 of both point formulas: b times two different elements
+        xs = [_random_fe(8, 1, seed=11), _random_fe(8, 2, seed=12)]
+        got = pk.FIELD.mul_many([(pk._B_FE, x) for x in xs])
+        for x, g in zip(xs, got):
+            assert np.array_equal(_limbs_of(g), _limbs_of(pk.FIELD.mul(pk._B_FE, x)))
+
+    def test_a_pair_over_the_bound_is_refused(self):
+        ok = (_random_fe(8, 1), _random_fe(8, 1))
+        with pytest.raises(AssertionError):
+            pk.FIELD.mul_many([ok, (_random_fe(8, 4), _random_fe(8, 5))])
+
+    def test_values_against_python_ints(self):
+        a, b = _random_fe(8, 4, seed=5), _random_fe(8, 4, seed=6)
+        (got,) = pk.FIELD.mul_many([(a, b)])
+        rinv = pow(R, -1, p256.P)
+        av = bn.limbs_to_ints(_limbs_of(a))
+        bv = bn.limbs_to_ints(_limbs_of(b))
+        assert bn.limbs_to_ints(_limbs_of(got)) == [
+            (x * y * rinv) % p256.P for x, y in zip(av, bv)
+        ]
+
+
+class TestStackedPointOps:
+    """The cases the complete formulas exist for, one per test id, through
+    the stacked `point_add` / `point_double` against the host oracle."""
+
+    G = p256.GENERATOR
+    G5 = p256.scalar_mult(5, p256.GENERATOR)
+
+    @pytest.mark.parametrize(
+        "case",
+        ["identity_plus_p", "p_plus_identity", "p_plus_p", "p_plus_minus_p",
+         "identity_plus_identity", "p_plus_q"],
+    )
+    def test_add_edge(self, case):
+        p, q = {
+            "identity_plus_p": (None, self.G5),
+            "p_plus_identity": (self.G5, None),
+            "p_plus_p": (self.G5, self.G5),
+            "p_plus_minus_p": (self.G5, p256.point_neg(self.G5)),
+            "identity_plus_identity": (None, None),
+            "p_plus_q": (self.G5, self.G),
+        }[case]
+        # the edge case rides among ordinary lanes, as in a real batch
+        ps, qs = [self.G, p, self.G5], [self.G5, q, self.G5]
+        got = read_affine(pk.point_add(make_point_batch(ps), make_point_batch(qs)))
+        assert got == [p256.point_add(a, b) for a, b in zip(ps, qs)]
+
+    @pytest.mark.parametrize("case", ["identity", "generator", "multiple"])
+    def test_double_edge(self, case):
+        p = {"identity": None, "generator": self.G, "multiple": self.G5}[case]
+        pts = [self.G5, p, self.G]
+        got = read_affine(pk.point_double(make_point_batch(pts)))
+        assert got == [p256.point_add(a, a) for a in pts]
+
+    def test_results_are_canonical_limbs_of_bound_one(self):
+        pts = make_point_batch([self.G, self.G5, None])
+        for res in (pk.point_add(pts, pts), pk.point_double(pts)):
+            for coord in res:
+                assert coord.bound == 1
+                limbs = _limbs_of(coord)
+                assert limbs.max() <= bn.LIMB_MASK
+                assert all(v < p256.P for v in bn.limbs_to_ints(limbs))
+
+
+def _loops_in(jaxpr):
+    """Loop primitives (`scan`, `while`) in a jaxpr, nested ones included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scan", "while"):
+            n += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _loops_in(sub)
+    return n
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """What `auto` resolves to on a TPU, whatever backend traces."""
+    monkeypatch.setenv("FABRIC_TPU_KERNEL_VARIANT", pk._AUTO_VARIANT["tpu"])
+    monkeypatch.setenv(
+        "FABRIC_TPU_CIOS_UNROLL", "1" if bn._AUTO_CIOS_UNROLLED["tpu"] else "0"
+    )
+
+
+class TestLoopCounts:
+    """The engagement proof of the stacked multiplies, decided at trace
+    time: a point operation traces 3 Montgomery loops (it traced 14 and
+    13), a Horner window 6 x 3 = 18 (it traced 80).  Trace only: nothing
+    is compiled or run."""
+
+    LANES = 8
+
+    def _point(self):
+        return jax.ShapeDtypeStruct((3, bn.NLIMBS, self.LANES), jnp.uint32)
+
+    @staticmethod
+    def _unstack(rows):
+        return pk._unpack_point([bn.split(rows[0]), bn.split(rows[1]), bn.split(rows[2])])
+
+    @pytest.mark.parametrize("op,want", [("point_add", 3), ("point_double", 3)])
+    def test_point_op_traces_three_loops(self, as_on_tpu, op, want):
+        if op == "point_add":
+            fn = lambda p, q: pk._pack_point(pk.point_add(self._unstack(p), self._unstack(q)))
+            args = (self._point(), self._point())
+        else:
+            fn = lambda p: pk._pack_point(pk.point_double(self._unstack(p)))
+            args = (self._point(),)
+        assert _loops_in(jax.make_jaxpr(fn)(*args).jaxpr) == want
+
+    def test_horner_window_traces_eighteen_loops(self, as_on_tpu):
+        digits = jax.ShapeDtypeStruct((pk.NUM_WINDOWS, self.LANES), jnp.uint32)
+        q_table = jax.ShapeDtypeStruct((16, 3, bn.NLIMBS, self.LANES), jnp.uint32)
+        g_table = jax.ShapeDtypeStruct((16, 3, bn.NLIMBS), jnp.uint32)
+        qx = jax.ShapeDtypeStruct((bn.NLIMBS, self.LANES), jnp.uint32)
+
+        def fn(d1, d2, qt, gt, qx_):
+            return pk._pack_point(pk._horner_loop(d1, d2, qt, gt, qx_))
+
+        jaxpr = jax.make_jaxpr(fn)(digits, digits, q_table, g_table, qx).jaxpr
+        windows = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+        assert len(windows) == 1 and windows[0].params["length"] == pk.NUM_WINDOWS
+        (body,) = jax.core.jaxprs_in_params(windows[0].params)
+        assert _loops_in(body) == 18
 
 
 class TestGTable:
