@@ -13,7 +13,6 @@ asked to write) and compared with the plain reference, exactly.
 
 from __future__ import annotations
 
-import math
 import os
 import time
 from typing import Dict, List
@@ -21,16 +20,23 @@ from typing import Dict, List
 from benchmarks import generator as gen
 from benchmarks import harness as hs
 from benchmarks import reference as ref
+from benchmarks import window_series as ws
 
 ANNOTATIONS = ("bench.submit",)
 WINDOW_SPAN = "bench.window"
+# the program's spans kept per block in the run's series (window_series.py)
+SERIES_SPANS = (
+    "pipeline.prepare", "pipeline.commit", "commit.await_verdicts",
+    "commit.validate", "commit.rwsets", "commit.assemble_pvt", "ledger.mvcc",
+    "ledger.block_append", "ledger.state_commit", "tpu.dispatch", "tpu.resolve",
+)
 
 
 def run(r: hs.Run) -> Dict:
     cfg, traffic = r.config, r.traffic
     warmup = int(traffic["warmup_blocks"])
-    n_blocks = warmup + math.ceil(
-        r.seconds * float(traffic["chain_blocks_per_second"])
+    n_blocks = hs.backlog_length(
+        warmup, traffic["chain_blocks_per_second"], r.seconds
     )
     r.mark("imports_done")
     world = gen.build_world(cfg)
@@ -97,6 +103,7 @@ def _run(r: hs.Run, world, workers, n_blocks: int, warmup: int) -> Dict:
     )
     checks = hs.Checks()
     submitted_at: Dict[int, float] = {}
+    unmarshal_ms: Dict[int, float] = {}
     exhausted = False
     try:
         # warm-up: the first blocks trace, lower and compile (or load) the
@@ -118,12 +125,13 @@ def _run(r: hs.Run, world, workers, n_blocks: int, warmup: int) -> Dict:
             hs.check_bucket(entry["lanes"], _bucket(entry["lanes"]), r.want_bucket)
         hs.GcLog.settle()
         r.mark("chain_built")
+        ledger_filesystem = hs.filesystem_type(ledger_dir)
         hs.say(
             phase="setup", seconds_since_start=r.marks, workload=r.workload,
             chain_blocks=len(chain),
             block_txs=int(cfg["block_txs"]), lanes_per_block=chain[0]["lanes"],
             bucket=_bucket(chain[0]["lanes"]),
-            ledger_filesystem=hs.filesystem_type(ledger_dir),
+            ledger_filesystem=ledger_filesystem, ledger_dir=ledger_dir,
             backend=provider.describe_backend(), warmup=warm_log,
             compile_cache_dir=hs.compile_cache_dir(),
             native_library=hs.native_library(),
@@ -139,8 +147,10 @@ def _run(r: hs.Run, world, workers, n_blocks: int, warmup: int) -> Dict:
                 if nxt >= len(chain):
                     exhausted = True
                     break
+                t_unmarshal = time.perf_counter()
                 block = gen.parse_block_bytes(chain[nxt]["raw"])
                 submitted_at[nxt] = time.perf_counter()
+                unmarshal_ms[nxt] = (submitted_at[nxt] - t_unmarshal) * 1e3
                 with hs.annotate("bench.submit", r.trace):
                     pipe.submit(block)
                 nxt += 1
@@ -162,6 +172,7 @@ def _run(r: hs.Run, world, workers, n_blocks: int, warmup: int) -> Dict:
     checks.add("blocks_never_committed", sent - len(committed_at))
     checks.seam("pipeline", lambda: hs.check_pipeline(pipe))
     checks.seam("drained", lambda: _require(drained, "drain timed out"))
+    checks.seam("ledger_fsync", lambda: hs.check_fsync_costs(ledger_filesystem))
     if r.device_path:
         checks.seam("provider", lambda: hs.check_provider_seams(provider))
         checks.seam(
@@ -204,8 +215,24 @@ def _run(r: hs.Run, world, workers, n_blocks: int, warmup: int) -> Dict:
         out["end_to_end"]["block_commit_p90_ms"] = hs.percentile(latencies, 90)
         lanes = [chain[n]["lanes"] for n in timed]
         out["layer"]["lanes_per_launch"] = sum(lanes) / len(lanes)
+    spans = (
+        hs.spans_in_window(r.obs, WINDOW_SPAN) if r.trace or r.series else []
+    )
     if r.trace:
-        out["layer"]["spans"] = hs.spans_in_window(r.obs, WINDOW_SPAN)
+        out["layer"]["spans"] = spans
+    if r.series:
+        out["series"] = {
+            "unit": "block",
+            "offered_at": [submitted_at[n] - t0 for n in timed],
+            "done_at": [committed_at[n] - t0 for n in timed],
+            "harness_ms": [unmarshal_ms[n] for n in timed],
+            "harness_work": "gen.parse_block_bytes: the deliver client's unmarshal",
+            "spans": {
+                name: [rows.get(n) for n in timed]
+                for name, rows in
+                ws.spans_per_unit(spans, SERIES_SPANS, "block").items()
+            },
+        }
     return out
 
 

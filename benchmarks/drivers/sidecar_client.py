@@ -15,7 +15,6 @@ compared, lane for lane, with the plain reference's.
 
 from __future__ import annotations
 
-import math
 import os
 import tempfile
 import threading
@@ -25,16 +24,22 @@ from typing import Dict, List
 from benchmarks import generator as gen
 from benchmarks import harness as hs
 from benchmarks import reference as ref
+from benchmarks import window_series as ws
 
 ANNOTATIONS = ("bench.batch_verify",)
 WINDOW_SPAN = "bench.window"
+# the program's spans kept per request in the run's series (window_series.py):
+# one client in a closed loop, so the k-th span of a name is the k-th request's
+SERIES_SPANS = (
+    "client.roundtrip", "serve.verify", "tpu.dispatch", "tpu.resolve",
+)
 
 
 def run(r: hs.Run) -> Dict:
     cfg, traffic = r.config, r.traffic
     warmup = int(traffic["warmup_requests"])
-    n_requests = warmup + math.ceil(
-        r.seconds * float(traffic["requests_built_per_second"])
+    n_requests = hs.backlog_length(
+        warmup, traffic["requests_built_per_second"], r.seconds
     )
     r.mark("imports_done")
     world = gen.build_world(cfg)
@@ -220,8 +225,24 @@ def _run(r: hs.Run, workers, n_requests: int, warmup: int) -> Dict:
         out["end_to_end"]["verdict_lanes_per_s"] = rate
         out["end_to_end"]["verdict_p95_ms"] = hs.percentile(walls, 95)
         out["layer"]["lanes_per_launch"] = sum(lanes) / len(lanes)
+    spans = (
+        hs.spans_in_window(r.obs, WINDOW_SPAN) if r.trace or r.series else []
+    )
     if r.trace:
-        out["layer"]["spans"] = hs.spans_in_window(r.obs, WINDOW_SPAN)
+        out["layer"]["spans"] = spans
+    if r.series:
+        in_order = ws.spans_in_order(spans, SERIES_SPANS)
+        out["series"] = {
+            "unit": "request",
+            "offered_at": [started_at[n] - t0 for n in done_at],
+            "done_at": [done_at[n] - t0 for n in done_at],
+            "harness_ms": [],
+            "harness_work": "none: the request's operands are built before the window",
+            "spans": {
+                name: rows for name, rows in in_order.items()
+                if len(rows) == len(done_at)
+            },
+        }
     return out
 
 

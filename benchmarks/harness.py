@@ -12,6 +12,7 @@ import json
 import math
 import os
 import shutil
+import signal
 import sys
 import time
 from typing import Callable, Dict, List, Optional, Sequence
@@ -222,6 +223,15 @@ def rate_in_window(done_at: Sequence[float], amounts: Sequence[float],
     return total / (closes - t0)
 
 
+def backlog_length(warmup: int, per_second: float, seconds: float) -> int:
+    """How many blocks (requests) a driver builds: the warm-up's, and
+    `per_second` (the traffic file's) for every second of the window.  The
+    traffic file sizes that at 2 x the rate the program sustained when it was
+    last read (its ``sustained_per_second``), so that a PR which speeds the
+    path up has room before ``chain_exhausted`` fails its runs."""
+    return warmup + math.ceil(seconds * float(per_second))
+
+
 # ---------------------------------------------------------------------------
 # the output check's book-keeping
 # ---------------------------------------------------------------------------
@@ -258,6 +268,22 @@ class Checks:
 # ---------------------------------------------------------------------------
 
 
+# A peer's ledger lives on a local disk; here it lives under WORK_ROOT, in the
+# checkout, whatever that is mounted from (9p on the chip machine of PR 26-33,
+# which has no disk: PERF.md section 2).  Never on a filesystem that lives in
+# memory: there the block store's fsync, which the configuration guarantees
+# before every commit callback, costs nothing.
+MEMORY_FILESYSTEMS = frozenset({"tmpfs", "ramfs", "devtmpfs", "hugetlbfs"})
+
+
+def check_fsync_costs(kind: str) -> None:
+    if kind in MEMORY_FILESYSTEMS:
+        raise SeamGaveWay(
+            f"the ledger directory is on a {kind}: its fsync is free, and the "
+            "configuration guarantees one before every commit callback"
+        )
+
+
 def fresh_workdir(workload: str) -> str:
     path = os.path.join(WORK_ROOT, workload)
     shutil.rmtree(path, ignore_errors=True)
@@ -289,6 +315,93 @@ def compile_cache_dir() -> Optional[str]:
     import jax
 
     return jax.config.jax_compilation_cache_dir
+
+
+# ---------------------------------------------------------------------------
+# the first run of a checkout: compile in a child, measure in a process that
+# loaded its programs from the cache, like every later run
+# ---------------------------------------------------------------------------
+
+# A process that has compiled the verify program itself commits blocks at
+# 0.6 x the rate, for the rest of its life, of one that loaded it from the
+# compile cache (PERF.md section 2), and the driver's sets hold each side's
+# first run.  So where nothing says that this checkout's cache holds this
+# cell's programs, a child makes a short run of the cell first and exits (one
+# process at a time owns the chip: this one has not touched JAX yet), and its
+# seconds are this run's set-up.
+COMPILE_CHILD_SECONDS = 2.0
+COMPILE_CHILD_TIMEOUT_S = 900.0
+
+
+def cache_marker_path(workload: str, cell_data: Dict) -> str:
+    """A file in the compile cache's directory (the program's own rule for
+    the place: fabric_tpu/utils/jaxcache.py) whose name says which sources,
+    which JAX and which cell's data a child compiled there."""
+    import hashlib
+    from importlib import metadata
+
+    from fabric_tpu.utils import jaxcache
+
+    digest = hashlib.sha256(
+        json.dumps(cell_data, sort_keys=True).encode("utf-8")
+    )
+    for package in ("jax", "jaxlib", "libtpu"):
+        try:
+            digest.update(f"{package}={metadata.version(package)};".encode())
+        except metadata.PackageNotFoundError:
+            digest.update(f"{package}=none;".encode())
+    program = os.path.join(REPO_ROOT, "fabric_tpu")
+    for folder, folders, files in os.walk(program):
+        folders.sort()
+        for name in sorted(files):
+            if name.endswith(".py"):
+                path = os.path.join(folder, name)
+                digest.update(os.path.relpath(path, program).encode("utf-8"))
+                with open(path, "rb") as fh:
+                    digest.update(fh.read())
+    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or jaxcache.CACHE_DIR
+    return os.path.join(
+        cache, f"bench_compiled.{workload}.{digest.hexdigest()[:16]}"
+    )
+
+
+def compile_in_a_child(workload: str, seed: int, cell_data: Dict,
+                       run_py: str) -> None:
+    """Unless a marker says it was done: a short run of the cell in a child
+    process, which compiles whatever the cache lacks, then the marker.  A
+    child that fails is reported and the run goes on; it then shows the
+    fault itself."""
+    import subprocess
+
+    marker = cache_marker_path(workload, cell_data)
+    if os.path.exists(marker):
+        return
+    t0 = time.perf_counter()
+    cmd = [
+        sys.executable, run_py, "--workload", workload, "--seed", str(seed),
+        "--seconds", str(COMPILE_CHILD_SECONDS), "--trace", "0",
+        "--compile-child",
+    ]
+    # its lines go to standard error: the last line of standard output is
+    # this run's result and no other
+    # (a session of its own, so that a child which hangs is ended with the
+    # workers it forked)
+    child = subprocess.Popen(cmd, cwd=REPO_ROOT, stdout=2, start_new_session=True)
+    try:
+        rc = child.wait(timeout=COMPILE_CHILD_TIMEOUT_S)
+    except subprocess.TimeoutExpired:
+        rc = None
+    finally:
+        if child.poll() is None:
+            os.killpg(child.pid, signal.SIGKILL)
+            child.wait()
+    seconds = round(time.perf_counter() - t0, 1)
+    if rc == 0:
+        os.makedirs(os.path.dirname(marker), exist_ok=True)
+        with open(marker, "w", encoding="utf-8") as fh:
+            json.dump({"workload": workload, "seconds": seconds}, fh)
+    say(phase="compile_child", workload=workload, rc=rc, seconds=seconds,
+        marker=marker)
 
 
 def native_library() -> str:
@@ -492,7 +605,7 @@ class Run:
                  t_process_start: float, chips: int = 1,
                  controls: Sequence[str] = (),
                  provider_factory: Optional[Callable] = None,
-                 serve_engine: Optional[str] = None):
+                 serve_engine: Optional[str] = None, series: bool = False):
         self.workload = workload
         self.config = dict(config)
         self.traffic = dict(traffic)
@@ -522,6 +635,9 @@ class Run:
         self.compiles: Optional[CompileLog] = None
         self.tracer = TraceWindow(trace, self.workdir)
         self.marks: Dict[str, float] = {}
+        # run.py --series PATH: the driver also reads the span ring after the
+        # window and hands back every block's (request's) times
+        self.series = series
 
     def mark(self, name: str) -> None:
         """Seconds since the process started, for the set-up line."""

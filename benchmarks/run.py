@@ -119,6 +119,18 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
         "false.  The result line is not touched",
     )
     ap.add_argument(
+        "--series", default=None, metavar="PATH",
+        help="write the window's per-block (per-request) series to PATH as "
+        "JSON: offered and done times, the harness's own work, the program's "
+        "spans per unit (benchmarks/window_series.py reads it)",
+    )
+    ap.add_argument(
+        "--compile-child", action="store_true",
+        help="this process is the child that a checkout's first run of a "
+        "cell starts to fill the compile cache (harness.compile_in_a_child): "
+        "it starts no child of its own",
+    )
+    ap.add_argument(
         "--keep-work", action="store_true",
         help="leave .bench_work/<workload> (ledger, profile) for a look by hand",
     )
@@ -137,11 +149,18 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     from benchmarks import harness as hs
 
     loaded = load_cell(args.workload)
+    if not (args.rehearse_on_cpu or args.compile_child):
+        hs.compile_in_a_child(
+            args.workload, args.seed,
+            {"config": loaded["config"], "traffic": loaded["traffic"]},
+            os.path.abspath(__file__),
+        )
     r = hs.Run(
         args.workload, loaded["config"], loaded["traffic"], args.seed,
         args.seconds, bool(args.trace), args.rehearse_on_cpu,
         T_PROCESS_START if argv is None else time.perf_counter(),
         chips=int(loaded["cell"]["chips"]), controls=controls_of(args),
+        series=bool(args.series),
     )
     return one_run(r, args, loaded)
 
@@ -153,6 +172,22 @@ def controls_of(args: argparse.Namespace) -> Sequence[str]:
     if any(rule not in ref.BREAK_RULES for rule in controls):
         raise SystemExit(f"benchmark: --control takes {ref.BREAK_RULES}")
     return controls
+
+
+def report_series(series: Optional[Dict], args: argparse.Namespace) -> None:
+    """Under --series PATH: what the window's spread is made of, on an earlier
+    line, and the whole series to PATH."""
+    from benchmarks import harness as hs
+    from benchmarks import window_series as ws
+
+    if not series or not series["done_at"]:
+        return
+    hs.say(phase="series", unit=series["unit"],
+           harness_work=series["harness_work"], **ws.summarize(series))
+    os.makedirs(os.path.dirname(os.path.abspath(args.series)), exist_ok=True)
+    with open(args.series, "w", encoding="utf-8") as fh:
+        json.dump(dict(series, workload=args.workload, seed=args.seed,
+                       seconds=args.seconds), fh)
 
 
 def one_run(r, args: argparse.Namespace, loaded: Dict) -> int:
@@ -174,6 +209,7 @@ def one_run(r, args: argparse.Namespace, loaded: Dict) -> int:
             return 2
         checks = outcome["checks"]
         measured = outcome["end_to_end"]
+        report_series(outcome.get("series"), args)
         metrics: Dict[str, Dict] = {}
         device = r.device_info()
         device["memory_peak_bytes"] = outcome["memory_peak_bytes"]

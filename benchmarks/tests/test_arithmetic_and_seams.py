@@ -128,3 +128,15 @@ def test_a_seam_that_gave_way_makes_the_run_incorrect(capsys):
     assert not checks.correct
     assert checks.rows["provider"] == {"value": 1, "limit": 0}
     assert "degraded" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("kind, gives_way", [
+    ("tmpfs", True), ("ramfs", True), ("9p", False), ("ext4", False),
+    ("overlay", False),
+])
+def test_a_ledger_in_memory_fails_the_run(kind, gives_way):
+    if gives_way:
+        with pytest.raises(hs.SeamGaveWay, match="fsync is free"):
+            hs.check_fsync_costs(kind)
+    else:
+        hs.check_fsync_costs(kind)
