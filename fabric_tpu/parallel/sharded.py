@@ -102,6 +102,26 @@ class ShardedVerify:
             self._flat = self._build_flat()
         return np.asarray(self._flat(e, r, s, qx, qy, ok))
 
+    def channels_program(self):
+        """The jitted channel-stack program (built once per mesh)."""
+        if self._channels is None:
+            self._channels = self._build_channels()
+        return self._channels
+
+    def dispatch_channels(self, e, r, s, qx, qy, ok):
+        """`verify_channels` up to the launch: the (C, B) mask as the
+        device array the jitted call returns, still sharded over the mesh
+        and maybe still being computed.  A caller that times the launch
+        and the wait apart, or reads where the output lives, copies it
+        back itself."""
+        c, _, b = e.shape
+        if b % self.data_size or c % self.channel_size:
+            raise ValueError(
+                f"stack ({c}, {b}) not divisible by mesh "
+                f"({self.channel_size}, {self.data_size})"
+            )
+        return self.channels_program()(e, r, s, qx, qy, ok)
+
     def verify_channels(
         self,
         e: np.ndarray,
@@ -112,15 +132,7 @@ class ShardedVerify:
         ok: np.ndarray,
     ) -> np.ndarray:
         """(C, 20, B) limb stacks + (C, B) mask -> (C, B) bool."""
-        c, _, b = e.shape
-        if b % self.data_size or c % self.channel_size:
-            raise ValueError(
-                f"stack ({c}, {b}) not divisible by mesh "
-                f"({self.channel_size}, {self.data_size})"
-            )
-        if self._channels is None:
-            self._channels = self._build_channels()
-        return np.asarray(self._channels(e, r, s, qx, qy, ok))
+        return np.asarray(self.dispatch_channels(e, r, s, qx, qy, ok))
 
 
 def channel_stack(
