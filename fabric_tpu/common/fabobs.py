@@ -296,6 +296,13 @@ CANONICAL_METRICS: Tuple[MetricSpec, ...] = (
         "ledger/kvledger.py _recover",
     ),
     MetricSpec(
+        "fabric_state_reads_total", "counter", ("how",),
+        "committed-state keys the commit path read, by how: preloaded "
+        "(the block's one bulk read) | point (a key the preload lacked, "
+        "or a metadata-only write's value)",
+        "ledger/statedb.py BlockPreload.account",
+    ),
+    MetricSpec(
         "fabric_ledger_torn_tail_total", "counter", ("store",),
         "torn tail records truncated on recovery (chain|pvtdata)",
         "ledger/blockstore.py _rebuild_index, ledger/pvtdatastore.py "

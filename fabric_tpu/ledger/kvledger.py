@@ -29,6 +29,7 @@ from fabric_tpu.ledger.mvcc import Validator
 from fabric_tpu.ledger.pvtdatastore import MissingEntry, PvtDataStore, PvtEntry
 from fabric_tpu.ledger.rwset import TxRwSet, Version
 from fabric_tpu.ledger.statedb import (
+    BlockPreload,
     HashedUpdateBatch,
     PvtUpdateBatch,
     UpdateBatch,
@@ -302,16 +303,11 @@ class KVLedger:
             else TxValidationCode(int(flags.asarray()[i]))
             for i in range(len(flags))
         ]
-        validator = Validator(self.state_db)
         # On replay the stored filter already includes MVCC verdicts; apply
         # writes of the VALID txs without re-deciding.
-        updates = UpdateBatch()
-        hashed = HashedUpdateBatch()
-        for tx_num, (rwset, code) in enumerate(zip(rwsets, codes)):
-            if code == TxValidationCode.VALID and rwset is not None:
-                validator._apply_write_set(
-                    rwset, Version(block.header.number, tx_num), updates, hashed
-                )
+        _, updates, hashed = Validator(self.state_db).validate_and_prepare_batch(
+            block.header.number, rwsets, codes, do_mvcc=False
+        )
         # pvt cleartext state is derived from the pvt store on replay
         if self.pvt_store.last_committed_block < block.header.number:
             self._repair_pvt_gap(block, rwsets, codes)
@@ -371,6 +367,7 @@ class KVLedger:
         rwsets: Optional[List[Optional[TxRwSet]]] = None,
         pvt_data: Optional[Dict[Tuple[int, str, str], bytes]] = None,
         missing_pvt: Optional[List[MissingEntry]] = None,
+        committed: Optional[BlockPreload] = None,
     ) -> ValidationFlags:
         """ValidateAndPrepare + commit (kv_ledger.go commit): assumes the
         block already carries the txvalidator's TRANSACTIONS_FILTER; MVCC
@@ -381,7 +378,11 @@ class KVLedger:
         `pvt_data` maps (tx_num, ns, collection) -> serialized cleartext
         KVRWSet assembled by the coordinator; writes are hash-checked
         against the tx's on-block hashed rwset before being applied
-        (kv_ledger.go CommitLegacy's pvt data validation)."""
+        (kv_ledger.go CommitLegacy's pvt data validation).
+
+        `committed` is the block's preload of committed rows where the
+        caller began one (Channel.store_block's policy stage): MVCC reads
+        only the rows it lacks."""
         import time as _time
 
         t0 = _time.perf_counter()
@@ -389,15 +390,22 @@ class KVLedger:
         if rwsets is None:
             rwsets = self._extract_rwsets(block)
         incoming = [TxValidationCode(int(c)) for c in flags.asarray()]
+        if committed is None:
+            committed = BlockPreload(self.state_db)
         if self.device_mvcc:
             from fabric_tpu.ledger.mvcc_device import DeviceValidator
 
             validator = DeviceValidator(self.state_db)
+            codes, updates, hashed = validator.validate_and_prepare_batch(
+                block.header.number, rwsets, incoming
+            )
         else:
-            validator = Validator(self.state_db)
-        codes, updates, hashed = validator.validate_and_prepare_batch(
-            block.header.number, rwsets, incoming
-        )
+            codes, updates, hashed = Validator(
+                self.state_db
+            ).validate_and_prepare_batch(
+                block.header.number, rwsets, incoming, committed=committed
+            )
+        state_reads = committed.account()
         # Assemble + hash-check private data FIRST: anything that can raise
         # must run before commit_hash is chained or any store is touched,
         # or a failed commit leaves this peer's COMMIT_HASH diverged from
@@ -469,7 +477,9 @@ class KVLedger:
         # the same four clock reads as spans, so every block's split is in
         # the flight ring and not the last block's alone
         number = block.header.number
-        fabobs.obs_record_span("ledger.mvcc", t0, t1, block=number)
+        fabobs.obs_record_span(
+            "ledger.mvcc", t0, t1, block=number, **state_reads
+        )
         fabobs.obs_record_span("ledger.block_append", t1, t2, block=number)
         fabobs.obs_record_span("ledger.state_commit", t2, t3, block=number)
         return flags

@@ -12,7 +12,13 @@ core/ledger/kvledger/txmgmt/validation/validator.go:82-281 exactly:
   (updates shadow, deletes hide) and compare results ->
   PHANTOM_READ_CONFLICT;
 - hashed (private-collection) reads check like public reads ->
-  MVCC_READ_CONFLICT.
+  MVCC_READ_CONFLICT;
+- the committed versions and metadata a block asks for are read once, in
+  bulk, before the scan (validator.go preLoadCommittedVersionOfRSet): a
+  statedb.BlockPreload, which the caller may hand in already holding the
+  rows the policy stage read.  In-block order is untouched: the running
+  batches are asked first, the preload holds only what was committed
+  before the block.
 
 This module is the oracle and the fallback; the device fixpoint path for
 the no-range-query common case lives in mvcc_device.py (SURVEY P5).
@@ -55,6 +61,7 @@ from fabric_tpu.ledger.rwset import (
     versions_same,
 )
 from fabric_tpu.ledger.statedb import (
+    BlockPreload,
     HashedUpdateBatch,
     UpdateBatch,
     VersionedDB,
@@ -119,6 +126,7 @@ class Validator:
         tx_rwsets: Sequence[Optional[TxRwSet]],
         incoming_codes: Sequence[TxValidationCode],
         do_mvcc: bool = True,
+        committed: Optional[BlockPreload] = None,
     ) -> Tuple[List[TxValidationCode], UpdateBatch, HashedUpdateBatch]:
         """Returns final per-tx codes plus the prepared update batches.
 
@@ -126,7 +134,13 @@ class Validator:
         only txs arriving VALID are MVCC-checked and applied
         (reference kvledger commit path: txvalidator flags first, then
         validateAndPrepareBatch skips already-invalid txs).
+
+        `committed` is this block's preload of committed rows, when the
+        caller already began one; the rows it lacks are read here, once.
         """
+        if committed is None:
+            committed = BlockPreload(self.db)
+        committed.load(*self._keys_to_preload(tx_rwsets, incoming_codes, do_mvcc))
         updates = UpdateBatch()
         hashed_updates = HashedUpdateBatch()
         out: List[TxValidationCode] = []
@@ -134,22 +148,61 @@ class Validator:
             if code != TxValidationCode.VALID or rwset is None:
                 out.append(code)
                 continue
-            vcode = self._validate_tx(rwset, updates, hashed_updates) if do_mvcc else TxValidationCode.VALID
+            vcode = (
+                self._validate_tx(rwset, updates, hashed_updates, committed)
+                if do_mvcc else TxValidationCode.VALID
+            )
             out.append(vcode)
             if vcode == TxValidationCode.VALID:
                 self._apply_write_set(
-                    rwset, Version(block_num, tx_num), updates, hashed_updates
+                    rwset, Version(block_num, tx_num), updates, hashed_updates,
+                    committed,
                 )
         return out, updates, hashed_updates
 
+    @staticmethod
+    def _keys_to_preload(tx_rwsets, incoming_codes, do_mvcc: bool):
+        """What the scan will ask committed state, over the txs that arrive
+        VALID: read keys (version), written keys (the metadata a value write
+        carries forward) and metadata-written keys (a metadata-only write to
+        an absent key is a no-op), public and hashed."""
+        keys: List[Tuple[str, str]] = []
+        hashed_keys: List[Tuple[str, str, bytes]] = []
+        for rwset, code in zip(tx_rwsets, incoming_codes):
+            if code != TxValidationCode.VALID or rwset is None:
+                continue
+            for ns_rw in rwset.ns_rw_sets:
+                ns = ns_rw.namespace
+                if do_mvcc:
+                    keys.extend((ns, r.key) for r in ns_rw.reads)
+                keys.extend((ns, w.key) for w in ns_rw.writes)
+                keys.extend((ns, mw.key) for mw in ns_rw.metadata_writes)
+                for coll in ns_rw.coll_hashed:
+                    cname = coll.collection_name
+                    if do_mvcc:
+                        hashed_keys.extend(
+                            (ns, cname, r.key_hash) for r in coll.hashed_reads
+                        )
+                    hashed_keys.extend(
+                        (ns, cname, w.key_hash) for w in coll.hashed_writes
+                    )
+                    hashed_keys.extend(
+                        (ns, cname, mw.key_hash) for mw in coll.metadata_writes
+                    )
+        return keys, hashed_keys
+
     # -- per-tx validation (validator.go validateTx) ----------------------
     def _validate_tx(
-        self, rwset: TxRwSet, updates: UpdateBatch, hashed_updates: HashedUpdateBatch
+        self,
+        rwset: TxRwSet,
+        updates: UpdateBatch,
+        hashed_updates: HashedUpdateBatch,
+        committed: BlockPreload,
     ) -> TxValidationCode:
         for ns_rw in rwset.ns_rw_sets:
             ns = ns_rw.namespace
             for read in ns_rw.reads:
-                if not self._validate_kv_read(ns, read, updates):
+                if not self._validate_kv_read(ns, read, updates, committed):
                     return TxValidationCode.MVCC_READ_CONFLICT
             for rqi in ns_rw.range_queries:
                 if not self._validate_range_query(ns, rqi, updates):
@@ -158,17 +211,20 @@ class Validator:
                 for hread in coll.hashed_reads:
                     if hashed_updates.contains(ns, coll.collection_name, hread.key_hash):
                         return TxValidationCode.MVCC_READ_CONFLICT
-                    committed = self.db.get_key_hash_version(
+                    was = committed.hashed_version(
                         ns, coll.collection_name, hread.key_hash
                     )
-                    if not versions_same(committed, hread.version):
+                    if not versions_same(was, hread.version):
                         return TxValidationCode.MVCC_READ_CONFLICT
         return TxValidationCode.VALID
 
-    def _validate_kv_read(self, ns: str, read: KVRead, updates: UpdateBatch) -> bool:
+    @staticmethod
+    def _validate_kv_read(
+        ns: str, read: KVRead, updates: UpdateBatch, committed: BlockPreload
+    ) -> bool:
         if updates.exists(ns, read.key):
             return False
-        return versions_same(self.db.get_version(ns, read.key), read.version)
+        return versions_same(committed.version(ns, read.key), read.version)
 
     def _validate_range_query(
         self, ns: str, rqi: RangeQueryInfo, updates: UpdateBatch
@@ -234,12 +290,16 @@ class Validator:
         height: Version,
         updates: UpdateBatch,
         hashed_updates: HashedUpdateBatch,
+        committed: Optional[BlockPreload] = None,
     ) -> None:
         """Apply one VALID tx's writes to the running batch, merging value
         and metadata updates like the reference's prepareTxOps: a
         value-only write carries forward the latest metadata, a
         metadata-only write carries forward the latest value (and is a
-        no-op if the key does not exist)."""
+        no-op if the key does not exist).  Without the block's preload
+        every committed row asked for is a point read."""
+        if committed is None:
+            committed = BlockPreload(self.db)
         txops: dict = {}  # (ns, coll, key) -> [flags, value, metadata]
 
         def op(ck):
@@ -290,11 +350,11 @@ class Validator:
             if upsert and not md_touched:
                 # merge the latest committed / in-block metadata
                 metadata = self._latest_metadata(
-                    ns, coll, key, updates, hashed_updates
+                    ns, coll, key, updates, hashed_updates, committed
                 )
             elif md_touched and not upsert:
                 value = self._latest_value(
-                    ns, coll, key, updates, hashed_updates
+                    ns, coll, key, updates, hashed_updates, committed
                 )
                 if value is None:
                     continue  # metadata on a non-existent key: no-op
@@ -303,26 +363,26 @@ class Validator:
             else:
                 hashed_updates.put(ns, coll, key, value, height, metadata)
 
-    def _latest_value(self, ns, coll, key, updates, hashed_updates):
+    @staticmethod
+    def _latest_value(ns, coll, key, updates, hashed_updates, committed):
         if coll == "":
             entry = updates.get(ns, key)
             if entry is not None:
                 return entry.value
-            vv = self.db.get_state(ns, key)
-            return vv.value if vv else None
+            return committed.value(ns, key)
         entry = hashed_updates.get(ns, coll, key)
         if entry is not None:
             return entry.value
-        vv = self.db.get_hashed_state(ns, coll, key)
-        return vv.value if vv else None
+        return committed.hashed_value(ns, coll, key)
 
-    def _latest_metadata(self, ns, coll, key, updates, hashed_updates):
+    @staticmethod
+    def _latest_metadata(ns, coll, key, updates, hashed_updates, committed):
         if coll == "":
             entry = updates.get(ns, key)
             if entry is not None:
                 return entry.metadata
-            return self.db.get_state_metadata(ns, key)
+            return committed.metadata(ns, key)
         entry = hashed_updates.get(ns, coll, key)
         if entry is not None:
             return entry.metadata
-        return self.db.get_hashed_metadata(ns, coll, key)
+        return committed.hashed_metadata(ns, coll, key)

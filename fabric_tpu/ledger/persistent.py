@@ -20,13 +20,14 @@ from __future__ import annotations
 import os
 import sqlite3
 import threading
-from typing import Iterator, List, Optional, Tuple
+from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
 from fabric_tpu.common.faults import fault_point
 from fabric_tpu.ledger import queries as rich_queries
 from fabric_tpu.ledger.rwset import Version
 from fabric_tpu.ledger.statedb import (
     BatchEntry,
+    CommittedRow,
     HashedUpdateBatch,
     PvtUpdateBatch,
     UpdateBatch,
@@ -60,6 +61,10 @@ CREATE TABLE IF NOT EXISTS meta (
   k TEXT PRIMARY KEY, v BLOB NOT NULL
 ) WITHOUT ROWID;
 """
+
+# keys per `IN (...)` list of load_committed: under SQLITE_MAX_VARIABLE_NUMBER,
+# which a build older than 3.32 still has at 999
+_IN_CHUNK = 900
 
 
 class SqliteVersionedDB:
@@ -163,6 +168,61 @@ class SqliteVersionedDB:
     ) -> Optional[Version]:
         vv = self.get_hashed_state(ns, coll, key_hash)
         return vv.version if vv else None
+
+    def load_committed(
+        self,
+        keys: Iterable[Tuple[str, str]],
+        hashed_keys: Iterable[Tuple[str, str, bytes]] = (),
+    ) -> Tuple[
+        Dict[Tuple[str, str], CommittedRow],
+        Dict[Tuple[str, str, bytes], CommittedRow],
+    ]:
+        """Bulk read for statedb.BlockPreload: (version, metadata) of every
+        key asked, None where the table holds no row; the value column is
+        not fetched.  One `IN` query per namespace (and collection) and
+        chunk of keys, all of them inside one read transaction, so the two
+        maps are one snapshot (stateleveldb has no bulk read; this is
+        statecouchdb's LoadCommittedVersions)."""
+        pub: Dict[Tuple[str, str], CommittedRow] = dict.fromkeys(keys)
+        hashed: Dict[Tuple[str, str, bytes], CommittedRow] = dict.fromkeys(
+            hashed_keys
+        )
+        # (ns,) or (ns, coll) -> the keys asked of it
+        groups: Dict[tuple, list] = {}
+        for *group, key in (*pub, *hashed):
+            groups.setdefault(tuple(group), []).append(key)
+        queries = []
+        for group, members in groups.items():
+            select = (
+                "SELECT key, block, txn, metadata FROM state "
+                "WHERE ns=? AND key IN (%s)"
+                if len(group) == 1 else
+                "SELECT keyhash, block, txn, metadata FROM hashed "
+                "WHERE ns=? AND coll=? AND keyhash IN (%s)"
+            )
+            for i in range(0, len(members), _IN_CHUNK):
+                chunk = members[i:i + _IN_CHUNK]
+                queries.append(
+                    (group, select % ",".join("?" * len(chunk)), (*group, *chunk))
+                )
+        with self._lock:
+            db = self._db
+            # one statement is its own snapshot; several share a transaction
+            own_txn = len(queries) > 1 and not db.in_transaction
+            if own_txn:
+                db.execute("BEGIN")
+            try:
+                for group, sql, params in queries:
+                    into = pub if len(group) == 1 else hashed
+                    for key, blk, txn, md in db.execute(sql, params):
+                        into[(*group, key)] = (
+                            Version(blk, txn),
+                            bytes(md) if md is not None else None,
+                        )
+            finally:
+                if own_txn:
+                    db.execute("COMMIT")
+        return pub, hashed
 
     def get_private_data(
         self, ns: str, coll: str, key: str

@@ -12,8 +12,9 @@ from __future__ import annotations
 
 import bisect
 from dataclasses import dataclass
-from typing import Dict, Iterator, List, NamedTuple, Optional, Tuple
+from typing import Dict, Iterable, Iterator, List, NamedTuple, Optional, Tuple
 
+from fabric_tpu.common import fabobs
 from fabric_tpu.ledger.rwset import Version
 
 
@@ -126,6 +127,126 @@ class PvtUpdateBatch:
         return len(self._updates)
 
 
+#: one committed row without its value: (version, metadata); None = the
+#: key is absent from committed state
+CommittedRow = Optional[Tuple[Version, Optional[bytes]]]
+
+
+class BlockPreload:
+    """The committed rows one block asks for, read in bulk and kept for that
+    block alone (reference validator.go preLoadCommittedVersionOfRSet ->
+    statedb.BulkOptimizable.LoadCommittedVersions): the policy stage's SBE
+    gate and the MVCC validator answer from the same map, so a key's row is
+    read once a block and not once per asker.  It holds what was committed
+    BEFORE the block, which cannot change while the one committer is inside
+    it; a key nobody loaded falls back to the db's point read, as
+    upstream's GetVersion does, so no answer can differ.  Values are not
+    held: `value()` reads the row unless the map already knows the key is
+    absent."""
+
+    __slots__ = (
+        "db", "_pub", "_hashed", "keys", "rows", "point_reads", "_accounted",
+    )
+
+    def __init__(self, db):
+        self.db = db
+        self._pub: Dict[Tuple[str, str], CommittedRow] = {}
+        self._hashed: Dict[Tuple[str, str, bytes], CommittedRow] = {}
+        self.keys = 0  # keys read in bulk
+        self.rows = 0  # ... of which committed state held a row
+        self.point_reads = 0  # lookups the map could not answer
+        self._accounted = (0, 0, 0)
+
+    def load(
+        self,
+        keys: Iterable[Tuple[str, str]],
+        hashed_keys: Iterable[Tuple[str, str, bytes]] = (),
+    ) -> None:
+        """Read the rows of `keys` ((ns, key)) and `hashed_keys` ((ns, coll,
+        key_hash)) that the map does not hold yet, in one bulk read."""
+        pub, hashed = self._pub, self._hashed
+        want = [k for k in keys if k not in pub]
+        want_hashed = [k for k in hashed_keys if k not in hashed]
+        if not want and not want_hashed:
+            return
+        got, got_hashed = self.db.load_committed(want, want_hashed)
+        pub.update(got)
+        hashed.update(got_hashed)
+        self.keys += len(got) + len(got_hashed)
+        self.rows += sum(
+            row is not None for row in (*got.values(), *got_hashed.values())
+        )
+
+    def counts(self) -> Tuple[int, int, int]:
+        return self.keys, self.rows, self.point_reads
+
+    def account(self) -> Dict[str, int]:
+        """What the block's stage that ends here read, that is since the
+        last call: counted into fabric_state_reads_total, once a stage and
+        not once a key, and returned as that stage's span attributes."""
+        now = self.counts()
+        keys, rows, point_reads = (
+            n - was for n, was in zip(now, self._accounted)
+        )
+        self._accounted = now
+        fabobs.obs_count("fabric_state_reads_total", keys, how="preloaded")
+        fabobs.obs_count("fabric_state_reads_total", point_reads, how="point")
+        return {"keys": keys, "rows": rows, "point_reads": point_reads}
+
+    # -- lookups: the map first, the db's point read on a miss --------------
+    def _row(self, ns: str, key: str) -> CommittedRow:
+        try:
+            return self._pub[(ns, key)]
+        except KeyError:
+            self.point_reads += 1
+            vv = self.db.get_state(ns, key)
+            return (vv.version, vv.metadata) if vv else None
+
+    def _hashed_row(self, ns: str, coll: str, key_hash: bytes) -> CommittedRow:
+        try:
+            return self._hashed[(ns, coll, key_hash)]
+        except KeyError:
+            self.point_reads += 1
+            vv = self.db.get_hashed_state(ns, coll, key_hash)
+            return (vv.version, vv.metadata) if vv else None
+
+    def version(self, ns: str, key: str) -> Optional[Version]:
+        row = self._row(ns, key)
+        return row[0] if row else None
+
+    def metadata(self, ns: str, key: str) -> Optional[bytes]:
+        row = self._row(ns, key)
+        return row[1] if row else None
+
+    def hashed_version(
+        self, ns: str, coll: str, key_hash: bytes
+    ) -> Optional[Version]:
+        row = self._hashed_row(ns, coll, key_hash)
+        return row[0] if row else None
+
+    def hashed_metadata(
+        self, ns: str, coll: str, key_hash: bytes
+    ) -> Optional[bytes]:
+        row = self._hashed_row(ns, coll, key_hash)
+        return row[1] if row else None
+
+    def value(self, ns: str, key: str) -> Optional[bytes]:
+        if self._pub.get((ns, key), True) is None:
+            return None  # known absent: nothing to read
+        self.point_reads += 1
+        vv = self.db.get_state(ns, key)
+        return vv.value if vv else None
+
+    def hashed_value(
+        self, ns: str, coll: str, key_hash: bytes
+    ) -> Optional[bytes]:
+        if self._hashed.get((ns, coll, key_hash), True) is None:
+            return None
+        self.point_reads += 1
+        vv = self.db.get_hashed_state(ns, coll, key_hash)
+        return vv.value if vv else None
+
+
 class VersionedDB:
     """Committed state: (ns, key) -> VersionedValue, ordered per namespace."""
 
@@ -171,6 +292,28 @@ class VersionedDB:
     def get_key_hash_version(self, ns: str, coll: str, key_hash: bytes) -> Optional[Version]:
         entry = self._hashed.get((ns, coll, key_hash))
         return entry.version if entry else None
+
+    def load_committed(
+        self,
+        keys: Iterable[Tuple[str, str]],
+        hashed_keys: Iterable[Tuple[str, str, bytes]] = (),
+    ) -> Tuple[
+        Dict[Tuple[str, str], CommittedRow],
+        Dict[Tuple[str, str, bytes], CommittedRow],
+    ]:
+        """Bulk read for BlockPreload: (version, metadata) of every key
+        asked, None where committed state holds no row (reference
+        statedb.BulkOptimizable.LoadCommittedVersions)."""
+        data = self._data
+        pub: Dict[Tuple[str, str], CommittedRow] = {}
+        for ns, key in keys:
+            vv = data.get(ns, {}).get(key)
+            pub[(ns, key)] = (vv.version, vv.metadata) if vv else None
+        hashed: Dict[Tuple[str, str, bytes], CommittedRow] = {}
+        for k in hashed_keys:
+            vv = self._hashed.get(k)
+            hashed[k] = (vv.version, vv.metadata) if vv else None
+        return pub, hashed
 
     def get_private_data(
         self, ns: str, coll: str, key: str

@@ -17,6 +17,7 @@ from typing import Callable, Optional
 from fabric_tpu.common import fabobs, flogging
 from fabric_tpu.crypto.bccsp import Provider, default_provider
 from fabric_tpu.ledger.kvledger import KVLedger
+from fabric_tpu.ledger.statedb import BlockPreload
 from fabric_tpu.msp.identity import MSPManager
 from fabric_tpu.protos import common_pb2, protoutil
 from fabric_tpu.validation.blockparse import parse_block
@@ -28,6 +29,20 @@ logger = flogging.must_get_logger("committer")
 
 class BlockVerificationError(Exception):
     pass
+
+
+def _written_keys(parsed):
+    """Every key the block writes, public and hashed, from the parse's
+    columnar table: all the SBE gate asks of committed state, and (where a
+    tx reads what it writes) all MVCC will."""
+    keys, hashed_keys = [], []
+    walk = getattr(parsed, "iter_written_keys", None)
+    for _tx, ns, coll, key in walk() if walk is not None else ():
+        if coll:
+            hashed_keys.append((ns, coll, key))
+        else:
+            keys.append((ns, key))
+    return keys, hashed_keys
 
 
 class Channel:
@@ -69,10 +84,16 @@ class Channel:
         self.fetch_pvt = fetch_pvt
         self.is_eligible = is_eligible
 
+        # the committed rows of the block whose policy stage is running,
+        # read in bulk and then handed on to MVCC; None outside that stage
+        self._committed: Optional[BlockPreload] = None
+
         def get_state_metadata(ns: str, coll: str, key) -> Optional[bytes]:
+            # asked outside store_block, an empty one: point reads
+            committed = self._committed or BlockPreload(self.ledger.state_db)
             if coll:
-                return self.ledger.state_db.get_hashed_metadata(ns, coll, key)
-            return self.ledger.state_db.get_state_metadata(ns, key)
+                return committed.hashed_metadata(ns, coll, key)
+            return committed.metadata(ns, key)
 
         self.validator = BlockValidator(
             channel_id,
@@ -140,13 +161,20 @@ class Channel:
             # closed), same as a synchronous batch_verify failure would.
             with fabobs.span("commit.await_verdicts", block=number):
                 ok_list = ok_list()
-        with fabobs.span("commit.validate", block=number):
+        committed = BlockPreload(self.ledger.state_db)
+        with fabobs.span("commit.validate", block=number) as validate_span:
+            committed.load(*_written_keys(parsed))
             sig_results = self.validator.finish_sig_results(
                 jobs, job_identity, ok_list
             )
-            flags = self.validator.validate(
-                block, parsed=parsed, sig_results=sig_results
-            )
+            self._committed = committed
+            try:
+                flags = self.validator.validate(
+                    block, parsed=parsed, sig_results=sig_results
+                )
+            finally:
+                self._committed = None
+            validate_span.set(**committed.account())
         t_validate = _time.perf_counter() - t0
         with fabobs.span("commit.rwsets", block=number):
             rwsets = [p.rwset for p in parsed]
@@ -169,7 +197,8 @@ class Channel:
         with fabobs.span("commit.assemble_pvt", block=number):
             pvt_data, missing = self._assemble_pvt_data(block, parsed, flags)
         result = self.ledger.commit(
-            block, rwsets=rwsets, pvt_data=pvt_data, missing_pvt=missing
+            block, rwsets=rwsets, pvt_data=pvt_data, missing_pvt=missing,
+            committed=committed,
         )
         if self.transient_store is not None:
             self.transient_store.purge_by_txids(

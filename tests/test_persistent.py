@@ -102,6 +102,90 @@ def test_sqlite_persists_across_reopen(tmp_path):
 
 
 # ----------------------------------------------------------------------
+# load_committed (the block preload's bulk read) and the differential runs
+# of tests/test_mvcc.py on the sqlite state db
+# ----------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("factory", [VersionedDB, "sqlite"])
+def test_load_committed_parity(factory, tmp_path):
+    db = (
+        SqliteVersionedDB(str(tmp_path / "s.db"))
+        if factory == "sqlite"
+        else factory()
+    )
+    _fill(db)
+    keys = [("ns1", "a"), ("ns1", "b"), ("ns1", "nope"), ("ns2", "z"),
+            ("ns3", "a"), ("ns1", "a")]
+    hashed_keys = [("ns1", "coll", b"\x01\x02"), ("ns1", "coll", b"\x09"),
+                   ("ns1", "other", b"\x01\x02")]
+    pub, hashed = db.load_committed(keys, hashed_keys)
+    assert pub == {
+        ("ns1", "a"): (Version(0, 0), None),
+        ("ns1", "b"): (Version(0, 1), b"md"),
+        ("ns1", "nope"): None,
+        ("ns2", "z"): (Version(0, 3), None),
+        ("ns3", "a"): None,
+    }
+    assert hashed == {
+        ("ns1", "coll", b"\x01\x02"): (Version(0, 1), b"hm"),
+        ("ns1", "coll", b"\x09"): None,
+        ("ns1", "other", b"\x01\x02"): None,
+    }
+    assert db.load_committed([]) == ({}, {})
+
+
+def _statements(db):
+    seen = []
+    db._db.set_trace_callback(lambda sql: seen.append(sql.split()[0]))
+    return seen
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_preload_equals_point_reads_on_sqlite(seed, tmp_path):
+    from test_mvcc import MVCC, POLICY_FAILURE, V, run_differential
+
+    db = SqliteVersionedDB(str(tmp_path / "s.db"))
+    assert {V, MVCC, POLICY_FAILURE} <= run_differential(db, seed)
+
+
+def test_preload_of_2500_keys_is_three_chunks_in_one_snapshot(tmp_path):
+    from test_mvcc import V, assert_same_as_point_reads, big_block
+
+    db = SqliteVersionedDB(str(tmp_path / "s.db"))
+    rows, txs = big_block()
+    batch = UpdateBatch()
+    for ns, key, value, version in rows:
+        batch.put(ns, key, value, version)
+    db.apply_updates(batch)
+    seen = _statements(db)
+    pub, _ = db.load_committed(
+        [("cc1", r.key) for t in txs for r in t.ns_rw_sets[0].reads]
+    )
+    # 900 + 900 + 700 keys of one namespace, inside one read transaction
+    assert seen == ["BEGIN", "SELECT", "SELECT", "SELECT", "COMMIT"]
+    assert not db._db.in_transaction
+    assert len(pub) == 2500
+    assert sum(1 for row in pub.values() if row is not None) == 1250
+    del seen[:]
+    (codes, updates, _), committed = assert_same_as_point_reads(
+        db, 5, txs, [V] * len(txs)
+    )
+    assert codes == [V] * len(txs) and len(updates) == len(txs)
+    assert committed.counts() == (2500, 1250, 0)
+    # the oracle's 5,000 point reads, then the preload's three
+    assert seen.count("SELECT") == 2 * 2500 + 3
+
+
+def test_one_statement_needs_no_transaction_of_its_own(tmp_path):
+    db = SqliteVersionedDB(str(tmp_path / "s.db"))
+    _fill(db)
+    seen = _statements(db)
+    db.load_committed([("ns1", "a"), ("ns1", "b")])
+    assert seen == ["SELECT"]
+
+
+# ----------------------------------------------------------------------
 # KVLedger: restart without replay
 # ----------------------------------------------------------------------
 

@@ -383,3 +383,82 @@ def test_channel_async_resolver_failure_fails_closed(tmp_path, world):
         pipe.stop()
     assert errors and "dispatch lost" in errors[0]
     assert ch.ledger.height == 0
+
+
+# ----------------------------------------------------------------------
+# One bulk read of committed state a block (statedb.BlockPreload): the
+# mechanism pinned by a count of the sqlite statements a block issues
+# ----------------------------------------------------------------------
+
+
+def _read_write_tx(world, key, version):
+    """The benchmark cell's tx: reads and writes one key of its own."""
+    bundle = create_proposal(world["client"], CHANNEL, "cc", [b"put", key.encode()])
+    results = serialize_tx_rwset(
+        rw.TxRwSet(
+            (
+                rw.NsRwSet(
+                    "cc",
+                    (rw.KVRead(key, version),),
+                    (rw.KVWrite(key, False, b"v"),),
+                ),
+            )
+        )
+    )
+    responses = [endorse_proposal(bundle, world["peer"], results)]
+    return create_signed_tx(bundle, world["client"], responses)
+
+
+def test_500_tx_block_reads_committed_state_in_one_statement(tmp_path, world):
+    from fabric_tpu.common import fabobs
+    from fabric_tpu.common.txflags import TxValidationCode
+
+    ch = Channel(CHANNEL, str(tmp_path), world["mgr"], world["registry"], PROVIDER)
+    n = 500
+    blocks, prev = [], b""
+    for num in range(2):
+        block = protoutil.new_block(num, prev)
+        for i in range(n):
+            # block 1 reads what block 0 wrote; its tx 7 reads a stale version
+            version = None
+            if num == 1:
+                version = rw.Version(0, i) if i != 7 else rw.Version(0, 499)
+            block.data.data.append(
+                _read_write_tx(world, f"k{i:03d}", version).SerializeToString()
+            )
+        protoutil.seal_block(block)
+        prev = protoutil.block_header_hash(block.header)
+        blocks.append(block)
+
+    statements = []
+    ch.ledger.state_db._db.set_trace_callback(
+        lambda sql: statements.append(sql.split()[0])
+    )
+    with fabobs.obs_installed(ring=4096) as reg:
+        for block in blocks:
+            del statements[:]
+            flags = ch.store_block(block)
+            # before the preload: 3 point SELECTs a tx, 1,500 a block
+            assert statements.count("SELECT") == 1
+        spans = {
+            (e["name"], e["args"]["block"]): e["args"]
+            for e in reg.trace_events()
+            if e["name"] in ("commit.validate", "ledger.mvcc")
+        }
+        series = reg.snapshot()["fabric_state_reads_total"]["series"]
+    assert [int(c) for c in flags.asarray()] == [
+        int(TxValidationCode.MVCC_READ_CONFLICT if i == 7 else TxValidationCode.VALID)
+        for i in range(n)
+    ]
+    assert ch.ledger.state_db.get_version("cc", "k008") == rw.Version(1, 8)
+    assert ch.ledger.state_db.get_version("cc", "k007") == rw.Version(0, 7)
+    # the policy stage reads the block's written keys; MVCC finds the keys
+    # it reads and writes already there, and reads nothing
+    for number, rows in ((0, 0), (1, n)):
+        validate = spans[("commit.validate", number)]
+        assert (validate["keys"], validate["rows"], validate["point_reads"]) == (n, rows, 0)
+        mvcc = spans[("ledger.mvcc", number)]
+        assert (mvcc["keys"], mvcc["rows"], mvcc["point_reads"]) == (0, 0, 0)
+    assert series == {"how=preloaded": 2.0 * n, "how=point": 0.0}
+    assert ch._committed is None  # dropped with the block
+    ch.ledger.close()

@@ -197,6 +197,70 @@ def test_metadata_only_write_merges_value(net, tmp_path):
     assert peer.ledger.state_db.get_state_metadata("sbecc", "ghost") is None
 
 
+@requires_crypto
+def test_vp_on_a_written_key_is_found_in_the_block_preload(net, tmp_path):
+    """Block 2 writes no metadata, so only the committed VALIDATION_PARAMETER
+    of `k` can send it down the sequential SBE path: the gate now reads it
+    from the block's one bulk read, and the path and the flags are as they
+    were with a point read per written key."""
+    from fabric_tpu.common import fabobs
+
+    set_vp = make_tx(
+        net,
+        writes=[("k", b"v0")],
+        metadata_writes=[rw.KVMetadataWrite("k", vp_entries("AND('Org2MSP.member')"))],
+        endorsers=("p1",),
+    )
+    block2 = [
+        make_tx(net, writes=[("other", b"o")], endorsers=("p1",)),
+        make_tx(net, writes=[("k", b"v1")], endorsers=("p1",)),  # fails k's VP
+        make_tx(net, writes=[("k", b"v2")], endorsers=("p2",)),
+        make_tx(net, writes=[("fresh", b"f")], endorsers=("p2",)),
+    ]
+    peer = Channel(CHANNEL, str(tmp_path / "peer"), net["mgr"], net["registry"], PROVIDER)
+    sbe_blocks = []
+    sequential = peer.validator._evaluate_policies_sbe
+
+    def spy(groups, parsed, flags, deps, plugin_results):
+        sbe_blocks.append(len(parsed))
+        return sequential(groups, parsed, flags, deps, plugin_results)
+
+    peer.validator._evaluate_policies_sbe = spy
+    chain = SoloChain(
+        CHANNEL, signer=net["oid"],
+        batch_config=BatchConfig(max_message_count=100),
+    )
+    blocks = []
+    chain.deliver = blocks.append
+    statements = []
+    peer.ledger.state_db._db.set_trace_callback(
+        lambda sql: statements.append(sql.split()[0])
+    )
+    with fabobs.obs_installed(ring=1024) as reg:
+        for envs in ([set_vp], block2):
+            for env in envs:
+                chain.order(env)
+            chain.flush()
+            del statements[:]
+            flags = peer.store_block(blocks[-1])
+        validate = [
+            e["args"] for e in reg.trace_events() if e["name"] == "commit.validate"
+        ][-1]
+    V = TxValidationCode
+    assert [int(c) for c in flags.asarray()] == [
+        int(V.VALID), int(V.ENDORSEMENT_POLICY_FAILURE), int(V.VALID), int(V.VALID),
+    ]
+    # block 1 by its metadata write, block 2 by the committed parameter
+    assert sbe_blocks == [1, 4]
+    # three distinct written keys, one of them committed; the gate, the
+    # key-level evaluator and MVCC all answered from that one read
+    assert (validate["keys"], validate["rows"], validate["point_reads"]) == (3, 1, 0)
+    assert statements.count("SELECT") == 1
+    assert peer.ledger.get_state("sbecc", "k") == b"v2"
+    md = deserialize_metadata(peer.ledger.state_db.get_state_metadata("sbecc", "k"))
+    assert VALIDATION_PARAMETER in md  # carried forward by the value write
+
+
 def test_metadata_serialization_roundtrip():
     entries = (("a", b"1"), (VALIDATION_PARAMETER, b"\x01\x02"))
     raw = serialize_metadata_entries(entries)
