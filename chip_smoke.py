@@ -716,7 +716,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
     from fabric_tpu.common import fabobs
     from fabric_tpu.crypto.bccsp import default_provider
-    from fabric_tpu.ops import bignum, p256_kernel
     from fabric_tpu.utils import native
 
     check_no_serve_env(os.environ)
@@ -736,8 +735,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         phase="start", device=device, seed=args.seed,
         native_library=native.available(),
         native_library_why_not=native.why_unavailable(),
-        kernel_variant=p256_kernel._kernel_variant(),
-        cios="unrolled" if bignum._cios_unrolled() else "looped",
         compile_cache_dir=jax.config.jax_compilation_cache_dir,
         rehearsal="cpu" if args.rehearse_on_cpu else None,
     )

@@ -14,7 +14,7 @@ statically both ways:
 * ``env-undeclared`` — an ``os.environ``/``os.getenv`` read of a
   ``FABRIC_TPU_*`` name that has no row here is a gate failure, and
 * ``env-dead`` — a row with no surviving reader anywhere in the tree
-  (bench.py, scripts and tests count, as deprecation grace) is too.
+  (scripts and tests count, as deprecation grace) is too.
 
 Dependency-free by design (stdlib ``dataclasses`` only): the tools
 layer AST-parses this file rather than importing it, and runtime
@@ -130,25 +130,6 @@ ENV_VARS: Tuple[EnvVar, ...] = (
         "2x the endpoint's observed p95, never a static knob): below "
         "this a hedge would race ordinary jitter, not a gray failure "
         "(malformed values fall back)",
-    ),
-    # -- device kernels -------------------------------------------------
-    EnvVar(
-        "FABRIC_TPU_KERNEL_VARIANT", "enum(inline|micro|microcond|auto)",
-        "auto",
-        "ops/p256_kernel.py _kernel_variant",
-        "force the ECDSA kernel trace shape; auto is per backend "
-        "(_AUTO_VARIANT: inline on CPU and, provisionally, on TPU — "
-        "ROADMAP D3)",
-    ),
-    EnvVar(
-        "FABRIC_TPU_CIOS_UNROLL", "enum(0|1)", "(auto: looped)",
-        "ops/bignum.py _cios_unrolled (bench.py and tests/conftest.py "
-        "pin it)",
-        "force the CIOS Montgomery multiply trace shape: 1 = 20 "
-        "unrolled iterations (one flat DAG; the whole verify program "
-        "then takes the TPU compiler over half an hour), 0 = "
-        "lax.fori_loop (compiles in minutes); auto is per backend "
-        "(_AUTO_CIOS_UNROLLED, provisional — ROADMAP D3)",
     ),
     # -- host crypto pools ----------------------------------------------
     EnvVar(
@@ -275,7 +256,7 @@ ENV_VARS: Tuple[EnvVar, ...] = (
         "directory for automatic Chrome-trace dumps on degrade/"
         "fail-closed triggers (capped per process)",
     ),
-    # -- test/bench harness knobs -----------------------------------------
+    # -- test harness knobs ----------------------------------------------
     EnvVar(
         "FABRIC_TPU_CACHE_DEBUG", "enum(0|1)", "0",
         "tests/conftest.py",

@@ -714,8 +714,7 @@ class ObsRegistry:
         return gather() if callable(gather) else ""
 
     def snapshot(self) -> Dict:
-        """JSON-able {family: {kind, series}} snapshot — what bench.py
-        attaches as ``configs.metrics_snapshot``.  Histogram series
+        """JSON-able {family: {kind, series}} snapshot.  Histogram series
         collapse to the bucket-quantized summary
         (:func:`metrics.summary_from_histogram_state`)."""
         out: Dict[str, Dict] = {}

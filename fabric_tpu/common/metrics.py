@@ -30,9 +30,9 @@ DEFAULT_BUCKETS = (
 def latency_summary(samples_s: Sequence[float]) -> Dict[str, float]:
     """``{n, p50_ms, p99_ms, max_ms}`` over seconds-valued latency
     samples (``{"n": 0}`` when empty) — the one quantile-index
-    definition shared by the serve sidecar's ServeStats, the commit
-    pipeline's stage reservoirs, and bench.py's client-side columns,
-    so the three surfaces can never silently diverge."""
+    definition shared by the serve sidecar's ServeStats and the commit
+    pipeline's stage reservoirs, so the two surfaces can never silently
+    diverge."""
     if not samples_s:
         return {"n": 0}
     s = sorted(samples_s)

@@ -1,9 +1,9 @@
 """numpy limb-matrix batch FP256BN pairing engine (hostbn) — the Idemix
 verify rung of the host ladder.
 
-The pure-Python Idemix oracle (idemix/scheme.py verify_signature)
-costs ~1 s/signature on the host (BENCH_r05.json) — the generic-Fp12 Miller loop pays
-an Fp12 inversion per line and the final exponentiation is a ~1020-bit
+The pure-Python Idemix oracle (idemix/scheme.py verify_signature) is
+slow on the host: the generic-Fp12 Miller loop pays an Fp12 inversion
+per line and the final exponentiation is a ~1020-bit
 square-and-multiply of schoolbook Fp12 products.  This module ports the
 PR 5 hostec_np playbook to the BN curve: the whole batch of signatures
 rides ``(NPAIRS, k·lanes)`` uint64 pair-limb matrices (the SAME

@@ -415,7 +415,7 @@ def _pool():
     plain fork risks workers wedged on a lock some other thread held
     mid-fork — prefer forkserver/spawn and let each worker rebuild the
     fixed-base tables (a few ms, once).  Note spawn-method workers also
-    re-import the parent's __main__ module, which can be heavy (bench.py
+    re-import the parent's __main__ module, which can be heavy (one that
     imports jax) — hence the pool_procs() clamp."""
     global _POOL, _POOL_PROCS
     with _POOL_LOCK:

@@ -367,8 +367,7 @@ class _WS:
 
 
 # p = 2^256 - 2^224 + 2^192 + 2^96 - 1: q*p decomposes into FIVE signed
-# shifted copies of q instead of an 11-row MAC (the pair-radix global
-# analog of the device kernel's per-limb qm_term shift decomposition).
+# shifted copies of q instead of an 11-row MAC.
 # In 2^26 columns relative to the REDC row i:
 #   -q           at col i+0   (absorbed: q IS t[i]'s low bits, and the
 #                              carry (t[i] - q) >> 26 == t[i] >> 26)
@@ -414,8 +413,7 @@ def _redc_rows(t, m_col, m0inv, blocks, tmp, q, c):
     each of the NPAIRS iterations, derive the quotient digit from the
     (exact) low bits of t[i], MAC q*m onto the nonzero row blocks of
     the modulus, and shift the retired limb's carry up.  m0inv == 1
-    (P-256's p ≡ -1 mod 2^26) makes the quotient digit free, the same
-    specialization the device kernel's qm_term exploits."""
+    (P-256's p ≡ -1 mod 2^26) makes the quotient digit free."""
     for i in range(NPAIRS):
         if m0inv == 1:
             q = np.bitwise_and(t[i], PAIR_MASK, out=q)

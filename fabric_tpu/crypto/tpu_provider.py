@@ -466,7 +466,3 @@ class TPUProvider(Provider):
             np.pad(a, [(0, 0), (0, pad)]) for a in arrays
         ) + (np.pad(ok.astype(bool), (0, pad)),)
 
-    def _run_kernel(self, limbs: Sequence[np.ndarray]) -> List[bool]:
-        n = limbs[-1].shape[0]
-        out = self._dispatch_limbs(limbs)
-        return list(np.asarray(out)[:n])

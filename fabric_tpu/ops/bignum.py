@@ -9,14 +9,12 @@ compiler-friendly integer math. Design notes:
   Montgomery multiplication runs with *lazy carries* entirely in uint32:
   each of the 20 outer iterations adds two <2^27 products per limb, for a
   worst-case accumulator below 20 * 2^27 * (1 + eps) < 2^32.
-- **Limb-unpacked representation**: inside kernels a big number is a
-  *tuple of 20 arrays*, each shaped (*batch) — plain SSA values. This is
-  the crucial TPU design choice: a stacked (20, B) layout forces
-  dynamic-index/concatenate ops inside the CIOS loop, each of which
-  breaks XLA fusion and round-trips every intermediate through HBM
-  (measured ~5x whole-kernel slowdown). Unpacked limbs give XLA one pure
-  elementwise DAG it can fuse freely; carries become ordinary data
-  dependencies. The batch dimension rides the VPU lanes.
+- **Limb-unpacked representation**: between multiplications a big number
+  is a *tuple of 20 arrays*, each shaped (*batch) — plain SSA values, so
+  additions, subtractions and carries are one elementwise DAG XLA fuses
+  freely and a carry is an ordinary data dependency. The batch dimension
+  rides the VPU lanes. A multiplication stacks its operands to
+  (20, *batch) for the CIOS loop (`mont_mul_l`).
 - **No constant-time requirement**: verification consumes public data
   (signatures, public keys, digests), so data-dependent selects are fine —
   but never data-dependent *shapes* or control flow; everything is one
@@ -149,7 +147,6 @@ class MontCtx:
         self.m = modulus
         r = 1 << RADIX_BITS
         self.m_limbs = int_to_limbs(modulus)
-        self.m_scalars = tuple(np.uint32(v) for v in self.m_limbs)
         self.m_scalars_i32 = tuple(np.int32(v) for v in self.m_limbs)
         self.r2_limbs = int_to_limbs((r * r) % modulus)
         self.one_mont = int_to_limbs(r % modulus)
@@ -161,50 +158,9 @@ class MontCtx:
             k: tuple(np.int32(v) for v in int_to_limbs(k * modulus))
             for k in range(1, 9)
         }
-        # Per-limb shift decomposition m_j = 2^a - 2^b (b = -1 for a plain
-        # power of two; None entry = limb is 0 or not decomposable). The
-        # crypto moduli are Solinas primes whose 13-bit limbs are almost
-        # all of this form — P-256's p decomposes COMPLETELY and has
-        # m0inv == 1, which turns the entire q*m half of CIOS (plus the
-        # REDC quotient multiply) into shifts and subtracts. Measured
-        # 1.46x on the TPU kernel's Montgomery multiply.
-        self.limb_shift_decomp: List = []
-        for v in self.m_limbs:
-            v = int(v)
-            d = None
-            if v == 0:
-                d = "zero"
-            else:
-                for hi in range(2 * LIMB_BITS + 1):
-                    if (1 << hi) == v:
-                        d = (hi, -1)
-                        break
-                    for lo in range(hi):
-                        if (1 << hi) - (1 << lo) == v:
-                            d = (hi, lo)
-                            break
-                    if d:
-                        break
-            self.limb_shift_decomp.append(d)
 
     def const(self, value_limbs: np.ndarray) -> Tuple[np.uint32, ...]:
         return tuple(np.uint32(v) for v in value_limbs)
-
-    def qm_term(self, q: jax.Array, j: int):
-        """q * m_j, as shifts/subtracts when the limb decomposes (never
-        underflows: 2^a - 2^b with a > b gives (q<<a) >= (q<<b)), else the
-        plain multiply. Returns None for zero limbs."""
-        d = self.limb_shift_decomp[j]
-        if d == "zero":
-            return None
-        if d is None:
-            return q * self.m_scalars[j]
-        hi, lo = d
-        if lo < 0:
-            return q << np.uint32(hi)
-        # interval domain sees [-(8191<<12), 8191<<13]; hi > lo makes the
-        # subtraction non-negative, bounded by q*m_j <= 8191*8192 < 2^26
-        return (q << np.uint32(hi)) - (q << np.uint32(lo))  # fabflow: disable=limb-overflow  # hi>lo => result in [0, 8191<<13 = 67100672 < 2**27]; relational fact outside the interval domain
 
 
 def cond_sub_l(ctx: MontCtx, xs: Sequence[jax.Array]) -> List[jax.Array]:
@@ -225,64 +181,17 @@ def reduce_canonical_l(ctx: MontCtx, xs: Sequence[jax.Array], times: int) -> Lis
 # ---------------------------------------------------------------------------
 # Core multiply (CIOS Montgomery, lazy carries)
 #
-# Two trace shapes for identical math, chosen by FABRIC_TPU_CIOS_UNROLL
-# (default: _AUTO_CIOS_UNROLLED below, by backend):
-# - unrolled: 20 Python iterations -> one flat elementwise DAG XLA fuses
-#   freely; ~40x the traced graph of the looped form.
-# - looped: lax.fori_loop whose body is ~10 vector ops on stacked
-#   (NLIMBS, B) arrays. XLA:CPU compiles the full ECDSA verify kernel
-#   in seconds instead of >10 minutes, the TPU compiler in minutes
-#   instead of not at all in useful time. Compiled for a v5e the loop is
-#   3-4 fusions, a pad and the counter a step, accumulator and operands in
-#   the on-chip vector memory (`S(1)`), the same program text at 2,048,
-#   4,096 and 6 x 4,096 lanes. On the chip (PR 31) a step over 6 x 2,048
-#   lanes costs what a step over 2,048 does (~1.4 us: the fixed cost of
-#   issuing its ops, not their width) and twice that over 6 x 4,096, so
-#   callers hand independent products to one call
-#   (fieldops.Field.mul_many) rather than loop once per product.
+# The outer i-loop is a lax.fori_loop whose body is ~10 vector ops on a
+# stacked (NLIMBS, B) accumulator; the inner j-loop is vectorized over
+# the limb axis.  Compiled for a v5e the loop is 3-4 fusions, a pad and
+# the counter a step, accumulator and operands in the on-chip vector
+# memory (`S(1)`), the same program text at 2,048, 4,096 and 6 x 4,096
+# lanes.  On the chip (PR 31) a step over 6 x 2,048 lanes costs what a
+# step over 2,048 does (~1.4 us: the fixed cost of issuing its ops, not
+# their width) and twice that over 6 x 4,096, so callers hand independent
+# products to one call (fieldops.Field.mul_many) rather than loop once
+# per product.
 # ---------------------------------------------------------------------------
-
-
-import contextlib as _contextlib
-import threading as _threading
-
-_cios_override = _threading.local()
-
-
-@_contextlib.contextmanager
-def force_looped_cios():
-    """Trace-time override: use the looped CIOS inside this context even
-    off-CPU. The pairing kernel traces hundreds of stacked multiplies
-    inside scan bodies; unrolled CIOS there multiplies an already
-    large graph (and its compile time) by the unroll factor."""
-    prev = getattr(_cios_override, "looped", False)
-    _cios_override.looped = True
-    try:
-        yield
-    finally:
-        _cios_override.looped = prev
-
-
-# `auto` per backend. The TPU entry is PROVISIONAL (ROADMAP D3): PR 22's
-# compile rehearsal found the unrolled form is what makes the verify
-# program uncompilable in useful time for a v5e (not finished after 30
-# minutes; the looped form compiles in 1.5-3), so `auto` is looped
-# everywhere until a benchmark shows the unrolled run time is worth its
-# compile. A backend that is not in the table is an error.
-_AUTO_CIOS_UNROLLED = {"cpu": False, "tpu": False}
-
-
-def _cios_unrolled() -> bool:
-    import os
-
-    if getattr(_cios_override, "looped", False):
-        return False
-    forced = os.environ.get("FABRIC_TPU_CIOS_UNROLL", "")
-    if forced == "1":
-        return True
-    if forced == "0":
-        return False
-    return _AUTO_CIOS_UNROLLED[jax.default_backend()]
 
 
 def mont_mul_l(
@@ -297,50 +206,6 @@ def mont_mul_l(
     output is < m*(1 + c1*c2*m/2^260), so nreduce=1 suffices for
     c1*c2 <= 16.
     """
-    if not _cios_unrolled():
-        return _mont_mul_l_looped(ctx, a, b, nreduce)
-    m0inv = ctx.m0inv
-    zero = jnp.zeros_like(a[0])
-    t: List[jax.Array] = [zero] * NLIMBS
-    # Static headroom proof (mechanized by tools/fabflow over this very
-    # loop): with canonical 13-bit limbs, each iteration adds at most
-    # ai*b[j] + q*m_j <= 8191^2 + 8191*2^13 = 134193153 < 2^27 per limb,
-    # plus the shifted-down carry (<= 327657).  The abstractly-unrolled
-    # 20-iteration worst case is 2684174334 < 0.625 * 2^32 < 2^32 - 1,
-    # so the uint32 lazy-carry accumulator can never wrap.  Adding ONE
-    # more accumulation term per iteration (e.g. a third product) would
-    # push the bound to ~0.94 * 2^32 and an extra limb (NLIMBS=21) to
-    # ~0.66 * 2^32 — the gate recomputes this on every change.
-    for i in range(NLIMBS):
-        ai = a[i]
-        t0 = t[0] + ai * b[0]
-        if int(m0inv) == 1:  # m ≡ -1 mod 2^13 (P-256's p): q is free
-            q = t0 & LIMB_MASK
-        else:
-            q = ((t0 & LIMB_MASK) * m0inv) & LIMB_MASK
-        qm0 = ctx.qm_term(q, 0)
-        carry0 = (t0 if qm0 is None else t0 + qm0) >> LIMB_BITS
-        # u_j for j=1..19, shifted down one limb; u_0's low bits vanish.
-        nt = []
-        for j in range(1, NLIMBS):
-            u = t[j] + ai * b[j]
-            qm = ctx.qm_term(q, j)
-            nt.append(u if qm is None else u + qm)
-        nt[0] = nt[0] + carry0
-        nt.append(zero)
-        t = nt
-    limbs, _ = carry_l(t)  # value < 2m for canonical inputs; carry_out 0
-    return reduce_canonical_l(ctx, limbs, nreduce)
-
-
-def _mont_mul_l_looped(
-    ctx: MontCtx,
-    a: Sequence[jax.Array],
-    b: Sequence[jax.Array],
-    nreduce: int,
-) -> List[jax.Array]:
-    """Same CIOS recurrence with the outer i-loop as lax.fori_loop and the
-    inner j-loop vectorized over a stacked (NLIMBS, B) accumulator."""
     from jax import lax
 
     batch = jnp.broadcast_shapes(
@@ -353,14 +218,22 @@ def _mont_mul_l_looped(
     )
     m0inv = ctx.m0inv
 
+    # Static headroom proof (mechanized by tools/fabflow, which unrolls
+    # lax.fori_loop(0, NLIMBS) over this very body): with canonical
+    # 13-bit limbs, each step adds at most ai*b[j] + q*m_j <= 2 * 8191^2
+    # = 134184962 < 2^27 per limb, plus the shifted-down carry (<= 2^19).
+    # fabflow keeps one interval for the whole stacked accumulator, so it
+    # charges every limb the carry; its 20-step worst case is 2687141695
+    # < 0.626 * 2^32 < 2^32 - 1, so the uint32 lazy-carry accumulator can
+    # never wrap.  Adding ONE more accumulation term per step (e.g. a
+    # third product) would push the bound to ~0.94 * 2^32, a second one
+    # past 2^32 — the gate recomputes this on every change.
     def body(i, t):
         ai = a_s[i]
         t0 = t[0] + ai * b_s[0]
         q = ((t0 & LIMB_MASK) * m0inv) & LIMB_MASK
         carry0 = (t0 + q * m_s[0]) >> LIMB_BITS
-        # same accumulator recurrence as the unrolled form: per-limb
-        # growth < 2^27 per step, 20-step worst case < 0.625 * 2^32
-        # (fabflow unrolls lax.fori_loop(0, NLIMBS) and re-proves it)
+        # u_j for j=1..19, shifted down one limb; u_0's low bits vanish.
         nt = t[1:] + ai * b_s[1:] + q * m_s[1:]
         nt = nt.at[0].add(carry0)
         return jnp.concatenate([nt, jnp.zeros_like(t[:1])])
@@ -368,7 +241,7 @@ def _mont_mul_l_looped(
     t = lax.fori_loop(
         0, NLIMBS, body, jnp.zeros_like(a_s), unroll=False
     )
-    limbs, _ = carry_l(split(t))
+    limbs, _ = carry_l(split(t))  # value < 2m for canonical inputs; carry_out 0
     return reduce_canonical_l(ctx, limbs, nreduce)
 
 

@@ -78,12 +78,7 @@ fe_mul = FIELD.mul
 fe_mul_many = FIELD.mul_many
 fe_add = FIELD.add
 fe_sub = FIELD.sub
-
-
-def fe_norm(a: FE) -> FE:
-    # (unconditional form: callers rely on bound-1 output even for
-    # bound-1 inputs annotated wider — see _horner_micro's renorm)
-    return FE(tuple(bn.reduce_canonical_l(CTX_P, a.limbs, a.bound - 1)), 1)
+fe_norm = FIELD.norm
 
 
 _B_FE = FE(bn.const_l(B_MONT), 1)
@@ -243,51 +238,18 @@ def _unpack_point(c: Sequence[Sequence[jax.Array]]) -> Point:
 
 
 # ---------------------------------------------------------------------------
-# Window-loop variants
+# The window loop
 #
-# Three trace shapes for the same math:
-# - "inline": 64-step scan whose body inlines 4 doubles + 2 adds (~6 point
-#   ops). The program tier-1 checks bit-exact against the oracle.
-# - "micro": 384-step UNIFORM scan whose body is a single complete
-#   point_add — completeness (RCB16) makes add(acc, acc) a correct double
-#   and handles the identity, so every step is the same op with a selected
-#   operand: 64 windows x [dbl,dbl,dbl,dbl,+Q(d2),+G(d1)]. The traced
-#   graph is ~6x smaller.
-# - "microcond": the micro scan with lax.switch on the step kind.
-# Override with FABRIC_TPU_KERNEL_VARIANT=inline|micro|microcond.
-#
-# `auto` per backend. The TPU entry is PROVISIONAL (ROADMAP D3): chosen
-# in PR 22 by one rule only — with the looped CIOS it compiles for a v5e
-# in minutes (scripts/chip_compile_rehearsal.py; the unrolled CIOS did
-# not finish in 30) — and it is the program the tests cover. Which
-# variant RUNS fastest on the chip is for a benchmark to decide. A
-# backend that is not in the table is an error, not a default.
+# One scan step is one whole window, its six point operations (4
+# doublings, + d2*Q, + d1*G) inlined.  A launch is bound by the device
+# ops it issues one after the other, and a step per point operation
+# would add to them: a selection from both tables and a choice of
+# operand (or a branch) at every step, and the point carried across a
+# loop boundary 384 times, not 64.
 # ---------------------------------------------------------------------------
-
-_AUTO_VARIANT = {"cpu": "inline", "tpu": "inline"}
-
-
-def _kernel_variant() -> str:
-    import os
-
-    forced = os.environ.get("FABRIC_TPU_KERNEL_VARIANT", "auto")
-    if forced in ("inline", "micro", "microcond"):
-        return forced
-    # a backend that cannot initialise raises here, at trace time: on the
-    # device path that is an error, not a reason to guess a variant
-    return _AUTO_VARIANT[jax.default_backend()]
 
 
 def _horner_loop(d1, d2, q_table, g_table, qx) -> Point:
-    variant = _kernel_variant()
-    if variant == "micro":
-        return _horner_micro(d1, d2, q_table, g_table, qx)
-    if variant == "microcond":
-        return _horner_microcond(d1, d2, q_table, g_table, qx)
-    return _horner_inline(d1, d2, q_table, g_table, qx)
-
-
-def _horner_inline(d1, d2, q_table, g_table, qx) -> Point:
     def win_body(carry, xs):
         d1w, d2w = xs
         acc = _unpack_point(carry)
@@ -299,83 +261,6 @@ def _horner_inline(d1, d2, q_table, g_table, qx) -> Point:
 
     carry, _ = lax.scan(
         win_body, _pack_point(point_identity_like(qx[0])), (d1, d2)
-    )
-    return _unpack_point(carry)
-
-
-def _horner_micro(d1, d2, q_table, g_table, qx) -> Point:
-    steps = NUM_WINDOWS * 6
-    kinds = jnp.asarray(np.tile([0, 0, 0, 0, 1, 2], NUM_WINDOWS), dtype=jnp.uint32)
-    digits = jnp.zeros((steps, d1.shape[1]), dtype=d1.dtype)
-    digits = digits.at[4::6].set(d2).at[5::6].set(d1)
-
-    def micro_body(carry, xs):
-        kind, digit = xs
-        # the carried x3 leaves point_add with bound 4 (y3/z3 are normed);
-        # renormalize so add(acc, acc) respects the lazy-reduction bounds
-        acc = Point(
-            fe_norm(FE(tuple(carry[0]), 4)), fe(carry[1]), fe(carry[2])
-        )
-        q_op = _select_point(q_table, digit)
-        g_op = _select_point(g_table, digit)
-
-        def mix(coord_idx):
-            a = [acc.x, acc.y, acc.z][coord_idx]
-            qo = [q_op.x, q_op.y, q_op.z][coord_idx]
-            go = [g_op.x, g_op.y, g_op.z][coord_idx]
-            is_dbl = kind == 0
-            is_q = kind == 1
-            return FE(
-                tuple(
-                    jnp.where(is_dbl, al, jnp.where(is_q, ql, gl))
-                    for al, ql, gl in zip(a.limbs, qo.limbs, go.limbs)
-                ),
-                1,
-            )
-
-        operand = Point(mix(0), mix(1), mix(2))
-        res = point_add(acc, operand)
-        return _pack_point(res), None
-
-    carry, _ = lax.scan(
-        micro_body, _pack_point(point_identity_like(qx[0])), (kinds, digits)
-    )
-    return _unpack_point(carry)
-
-
-def _horner_microcond(d1, d2, q_table, g_table, qx) -> Point:
-    """384-step scan like _horner_micro, but the body dispatches through
-    lax.switch on the step kind (a scalar scan input, so XLA's
-    conditional runs ONE branch at runtime): double steps run
-    point_double and skip the 16-entry table contractions entirely —
-    they are 4 of every 6 steps, so most iterations avoid both the
-    q-table one-hot reduction and the 3-way operand mix. Graph size
-    stays scan-body-bounded (~3 point ops)."""
-    steps = NUM_WINDOWS * 6
-    kinds = jnp.asarray(np.tile([0, 0, 0, 0, 1, 2], NUM_WINDOWS), dtype=jnp.int32)
-    digits = jnp.zeros((steps, d1.shape[1]), dtype=d1.dtype)
-    digits = digits.at[4::6].set(d2).at[5::6].set(d1)
-
-    def micro_body(carry, xs):
-        kind, digit = xs
-        acc = Point(
-            fe_norm(FE(tuple(carry[0]), 4)), fe(carry[1]), fe(carry[2])
-        )
-
-        def do_double(_):
-            return _pack_point(point_double(acc))
-
-        def do_add_q(_):
-            return _pack_point(point_add(acc, _select_point(q_table, digit)))
-
-        def do_add_g(_):
-            return _pack_point(point_add(acc, _select_point(g_table, digit)))
-
-        res = lax.switch(kind, (do_double, do_add_q, do_add_g), None)
-        return res, None
-
-    carry, _ = lax.scan(
-        micro_body, _pack_point(point_identity_like(qx[0])), (kinds, digits)
     )
     return _unpack_point(carry)
 

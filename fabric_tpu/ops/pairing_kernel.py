@@ -365,8 +365,7 @@ class Ate2Kernel:
             )
             self._sharded_fns[(id(mesh), axis, bucket)] = fn
         cols = self._mont_cols(list(pairs), bucket)
-        with bn.force_looped_cios():
-            mask_out = fn(self._w_arrs, *cols)
+        mask_out = fn(self._w_arrs, *cols)
         return [bool(v) for v in np.asarray(mask_out)[:n]]
 
     def _mont_cols(self, pairs, bucket):
@@ -404,9 +403,8 @@ class Ate2Kernel:
         n = len(pairs)
         bucket = force_bucket or next(b for b in _BUCKETS if n <= b)
         cols = self._mont_cols(pairs, bucket)
-        with bn.force_looped_cios():
-            # async dispatch: the mask materializes in check()'s drain
-            return self._fn(self._w_arrs, *cols)
+        # async dispatch: the mask materializes in check()'s drain
+        return self._fn(self._w_arrs, *cols)
 
 
 @lru_cache(maxsize=1)
@@ -434,20 +432,18 @@ def miller2_host_values(
             for x in f12.to_mont_int(v)
         )
 
-    with bn.force_looped_cios():
-
-        @jax.jit
-        def run():
-            return tuple(
-                f12.pack(f)
-                for f in _miller2(
-                    k._w_arrs, k.sched_g,
-                    col(p1[0]), col(p1[1]), col(p2[0]), col(p2[1]),
-                    like,
-                )
+    @jax.jit
+    def run():
+        return tuple(
+            f12.pack(f)
+            for f in _miller2(
+                k._w_arrs, k.sched_g,
+                col(p1[0]), col(p1[1]), col(p2[0]), col(p2[1]),
+                like,
             )
+        )
 
-        f1_st, f2_st = run()
+    f1_st, f2_st = run()
     return (
         f12.fp12_to_host(f12.unpack(f1_st)),
         f12.fp12_to_host(f12.unpack(f2_st)),

@@ -8,8 +8,6 @@ goroutines. The TPU-native equivalents here:
 - `sharded.ShardedVerify`: the batched ECDSA kernel jitted over a mesh —
   batch lanes sharded over "data" (P2/P6), whole channels sharded over
   "channel" (P3), masks all-gathered over ICI.
-- `provider.MeshTPUProvider`: drop-in BCCSP provider that spreads one
-  channel's (tx x sig) batch over every device.
 - `multichannel.MultiChannelValidator`: validates one block per channel
   in a single device step (BASELINE config #5: 4 channels x 2k tx).
 - `batcher.VerifyBatcher`: cross-channel verify coalescing with bounded
@@ -23,7 +21,6 @@ from fabric_tpu.parallel.mesh import (
     grid_mesh,
 )
 from fabric_tpu.parallel.sharded import ShardedVerify
-from fabric_tpu.parallel.provider import MeshTPUProvider
 from fabric_tpu.parallel.multichannel import MultiChannelValidator
 from fabric_tpu.parallel.batcher import BatchingProvider, VerifyBatcher
 
@@ -34,7 +31,6 @@ __all__ = [
     "flat_mesh",
     "grid_mesh",
     "ShardedVerify",
-    "MeshTPUProvider",
     "MultiChannelValidator",
     "VerifyBatcher",
 ]

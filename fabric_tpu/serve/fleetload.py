@@ -8,8 +8,8 @@ the ``SidecarProvider`` (or the ``SidecarRouter`` when ``--endpoints``
 lists a fleet) under one channel + admission class, asserting every
 mask against the by-construction ground truth, and prints ONE JSON
 summary line (requests, ok, mask_mismatches, busy_rejects, degraded,
-p50/p99 ms, lanes/s) — the contract ``bench.py configs.fleet`` and
-``tests/test_fleet.py`` drive as subprocesses::
+p50/p99 ms, lanes/s) — the contract ``tests/test_fleet.py`` drives as
+subprocesses::
 
     python -m fabric_tpu.serve.fleetload --address /tmp/s.sock \
         --channel paychan --qos high --requests 16 --lanes 256 --seed 3

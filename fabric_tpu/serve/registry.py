@@ -20,8 +20,7 @@ Warm-start discipline (three rungs, best to worst):
 
 Every rung is accounted per bucket (``stats()``): compile wall ms,
 whether the AOT artifact hit, how many XLA compile events fired — the
-numbers bench.py records as ``configs.serve`` and the warm-restart test
-asserts on.
+numbers the warm-restart test asserts on.
 
 The registry is engine-generic: production wires the ECDSA limb kernel
 (``ops.p256_kernel.verify_batch_device``); the CI-able ladder wires

@@ -3217,8 +3217,8 @@ def run_scenarios(
 
 
 def scorecard_for_bench(seed: int = 7, scale: float = 1.0) -> Dict:
-    """Compact scorecard for bench.py's BENCH_*.json: smoke scenarios
-    plus the per-stage latency summary."""
+    """Compact scorecard: smoke scenarios plus the per-stage latency
+    summary."""
     card = run_scenarios(SMOKE, seed=seed, scale=scale)
     return {
         "seed": seed,

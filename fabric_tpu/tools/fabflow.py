@@ -25,8 +25,8 @@ common/fp256bn, crypto/hostec, ledger/mvcc_device):
   widening fixpoint.  Calls into other analyzed modules are summarized
   interprocedurally (memoized per argument signature).  MontCtx
   instances are modeled by a contract table (per-limb scalars are
-  13-bit; ``qm_term(q, j) <= q << LIMB_BITS``) — the table IS the
-  per-limb fact base the headroom proof rests on.
+  13-bit) — the table IS the per-limb fact base the headroom proof
+  rests on.
 
   Unknown values (⊤) produce no findings: the gate proves what it can
   reach and stays quiet where precision runs out, so every finding is a
@@ -618,15 +618,35 @@ class ConstVal(AbsVal):
 
 
 class FuncVal(AbsVal):
-    """A function defined in an analyzed module (optionally bound)."""
+    """A function defined in an analyzed module (optionally bound).
 
-    __slots__ = ("mod", "node", "qualname", "selfval")
+    `closure` is the environment of the function a nested def or lambda
+    was defined in — the dict itself, not a copy, so a call sees what the
+    enclosing names hold when it is made, as Python does.  Without it the
+    body of a `lax.fori_loop` / `lax.scan` reads ⊤ for every operand it
+    closes over, and a proof about the loop is a proof about nothing."""
 
-    def __init__(self, mod, node, qualname, selfval=None):
+    __slots__ = ("mod", "node", "qualname", "selfval", "closure")
+
+    def __init__(self, mod, node, qualname, selfval=None, closure=None):
         self.mod = mod
         self.node = node
         self.qualname = qualname
         self.selfval = selfval
+        self.closure = closure
+
+    def closure_key(self):
+        """The closed-over values a call can read, for the call memo."""
+        if not self.closure:
+            return ()
+        read = {
+            n.id for n in ast.walk(self.node)
+            if isinstance(n, ast.Name) and isinstance(n.ctx, ast.Load)
+        }
+        return tuple(
+            (name, self.closure[name].key())
+            for name in sorted(read & self.closure.keys())
+        )
 
     def key(self, depth: int = 3):
         return ("F", self.mod.name, self.qualname)
@@ -863,8 +883,6 @@ _INTRINSIC_MODULES = {
 def _montctx_attr(name: str) -> AbsVal:
     if name in ("m_limbs", "r2_limbs", "one_mont", "one"):
         return limb_seq()
-    if name in ("m_scalars",):
-        return limb_seq()
     if name in ("m_scalars_i32",):
         return limb_seq(dtype="int32")
     if name == "m0inv":
@@ -875,33 +893,10 @@ def _montctx_attr(name: str) -> AbsVal:
         return SeqVal(items=None, elem=limb_seq(dtype="int32"))
     if name == "m":
         return Num(Interval(1, (1 << 256) - 1), "pyint")
-    if name == "limb_shift_decomp":
-        # per-limb (hi, lo) with 2^hi - 2^lo == m_j < 2^13, so hi <= 13
-        # and -1 <= lo < hi (lo == -1 marks a plain power of two)
-        return SeqVal(
-            items=None,
-            elem=SeqVal(
-                items=[
-                    Num(Interval(0, LIMB_BITS), "pyint"),
-                    Num(Interval(-1, LIMB_BITS - 1), "pyint"),
-                ],
-                mutable=False,
-            ),
-        )
     return UNKNOWN
 
 
 def _montctx_method(name: str):
-    if name == "qm_term":
-        def qm_term(args, kwargs, interp, node):
-            # q * m_j as shifts/subtracts or a plain multiply; every form
-            # is bounded by q << LIMB_BITS (m_j < 2^13), never negative.
-            q = args[0] if args else UNKNOWN
-            hi: Optional[int] = None
-            if isinstance(q, Num) and q.ivl.hi is not None:
-                hi = q.ivl.hi << LIMB_BITS
-            return Num(Interval(0, hi), "uint32")
-        return qm_term
     if name == "const":
         def const(args, kwargs, interp, node):
             return limb_seq()
@@ -1016,6 +1011,7 @@ class Analyzer:
             fv.qualname,
             tuple(a.key() for a in args),
             tuple(sorted((k, v.key()) for k, v in kwargs.items())),
+            fv.closure_key(),
         )
         if key in self.memo:
             return self.memo[key]
@@ -1030,7 +1026,7 @@ class Analyzer:
         return out
 
     def _run_callable(self, fv, node, args, kwargs, depth, budget) -> AbsVal:
-        env: Dict[str, AbsVal] = {}
+        env: Dict[str, AbsVal] = dict(fv.closure) if fv.closure else {}
         a = node.args
         pos = list(args)
         params = list(a.posonlyargs) + list(a.args)
@@ -1397,7 +1393,7 @@ class Interp:
                 return envm[name]
         # canonical-constant fallback: fixtures importing the limb
         # constants resolve even when bignum itself is not analyzed
-        if module.endswith("bignum") or module.endswith(".common"):
+        if module.endswith(("bignum", "limbparams", ".common")):
             if name == "LIMB_BITS":
                 return num_const(LIMB_BITS)
             if name == "NLIMBS":
@@ -1409,7 +1405,10 @@ class Interp:
         return UNKNOWN
 
     def exec_FunctionDef(self, node) -> None:
-        self.env[node.name] = FuncVal(self.mod, node, node.name)
+        self.env[node.name] = FuncVal(
+            self.mod, node, node.name,
+            closure=self.env if self.depth > 0 else None,
+        )
 
     exec_AsyncFunctionDef = exec_FunctionDef
 
@@ -1857,7 +1856,10 @@ class Interp:
         return UNKNOWN
 
     def eval_Lambda(self, node) -> AbsVal:
-        return FuncVal(self.mod, node, f"<lambda:{node.lineno}>")
+        return FuncVal(
+            self.mod, node, f"<lambda:{node.lineno}>",
+            closure=self.env if self.depth > 0 else None,
+        )
 
     def eval_IfExp(self, node) -> AbsVal:
         t = truth(self.eval(node.test))
@@ -1992,6 +1994,10 @@ class Interp:
     def _clamp(self, v: Num) -> Num:
         """After a reported overflow, continue with the full container
         range (the wrapped value is somewhere in it)."""
+        if v.ivl.lo is None or v.ivl.hi is None:
+            # nothing was reported (provenance unknown): the value stays
+            # unknown, or the next add would report a bound nobody computed
+            return v
         if dtype_is_lane_int(v.dtype):
             lo, hi, _ = DTYPES[v.dtype]
             if not v.ivl.within(lo, hi):

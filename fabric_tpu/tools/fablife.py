@@ -153,7 +153,7 @@ RULES: Dict[str, str] = {
 }
 
 #: lifetime + wire rules pin the runtime package; the tempdir facet of
-#: fd-leak additionally covers tests/ and bench.py — a leaked fd dies
+#: fd-leak additionally covers tests/ — a leaked fd dies
 #: with the test process, a leaked /tmp dir accumulates across every CI
 #: run of an hours-long soak.
 PKG_SCOPE = ("*fabric_tpu/*",)

@@ -17,8 +17,8 @@ env-undeclared    an ``os.environ``/``os.getenv`` read of a
                   ``FABRIC_TPU_*`` name with no row in the central
                   registry ``fabric_tpu/common/envreg.py``.
 env-dead          a registry row with no surviving reference anywhere
-                  in the scanned tree (bench.py and tests count as
-                  readers — deprecation grace).
+                  in the scanned tree (tests count as readers —
+                  deprecation grace).
 metric-unknown    a ``obs_count``/``obs_gauge``/``obs_observe`` emit
                   naming a family absent from ``CANONICAL_METRICS``
                   (the registry swallows it at runtime; the scrape
@@ -90,7 +90,7 @@ RULES: Dict[str, str] = {
     ),
     "env-dead": (
         "envreg.py row with no surviving reference in the scanned tree "
-        "(bench.py/tests count as readers)"
+        "(tests count as readers)"
     ),
     "metric-unknown": (
         "obs_count/obs_gauge/obs_observe emit naming a family absent from "
@@ -447,7 +447,7 @@ def _check_env(scan: Scan, active: Set[str]) -> List[Finding]:
                     Finding(
                         "env-dead", scan.envreg_path or "", line, 0,
                         f"registry row {name!r} has no reader anywhere in "
-                        f"the scanned tree (bench.py/tests count); delete "
+                        f"the scanned tree (tests count); delete "
                         f"the row or the feature it described",
                     )
                 )
@@ -697,9 +697,9 @@ def _check_suppression_stale(
         ):
             # a gate that only analyzes the package tree never honors
             # comments outside it — they are inert, not stale; tools
-            # whose gates also scan tests/ and bench.py (fabreg,
-            # fablife) declare pkg_scope_only=False in the registry and
-            # are judged everywhere they are honored
+            # whose gates also scan tests/ (fabreg, fablife) declare
+            # pkg_scope_only=False in the registry and are judged
+            # everywhere they are honored
             continue
         by_tool.setdefault(c.tool, []).append(c)
 

@@ -64,8 +64,8 @@ class AnalyzerSpec:
 
     ``pkg_scope_only``: True when the tool's CI gate analyzes only the
     package tree — its suppression comments outside it are inert and
-    never judged stale.  Tools whose gates also scan tests/ and
-    bench.py (fabreg, fablife) set False."""
+    never judged stale.  Tools whose gates also scan tests/
+    (fabreg, fablife) set False."""
 
     name: str
     module: str

@@ -2,7 +2,7 @@
 
 `jax.devices()` initializes the backend on first call, and a backend
 init can fail (UNAVAILABLE) or hang. Everything that *optionally* uses
-the device (bccsp.default_provider, bench.py, CLI probes) goes through
+the device (bccsp.default_provider, CLI probes) goes through
 this module instead of calling jax.devices() inline. A program that
 REQUIRES the device (chip_smoke.py) calls jax.devices() itself and
 fails on what it finds.
@@ -84,65 +84,3 @@ def probe_error() -> Optional[str]:
 def accelerator_present(timeout_s: Optional[float] = None) -> bool:
     devs = probe_devices(timeout_s)
     return bool(devs) and any(d.platform != "cpu" for d in devs)
-
-
-# -- out-of-process probe ---------------------------------------------------
-#
-# The daemon-thread probe above bounds the CALLER's wait but cannot kill
-# a backend init that wedges (the thread then sits in it forever).  The
-# subprocess probe gets a HARD bound — the kernel kills
-# the child — at the cost of a fresh interpreter + jax import per cold
-# probe (~10s on a healthy box), so it suits batch/CLI entrypoints
-# (bench.py) rather than the library path: bccsp.default_provider keeps
-# the cheap in-process probe, whose worst case is one wedged daemon
-# thread in a process that has already degraded to the software
-# provider.
-
-_sub_state: dict = {}
-
-
-def probe_subprocess(timeout_s: float):
-    """(ok, error): ok iff a non-CPU accelerator answered from a freshly
-    spawned python within timeout_s.  Cached for the process."""
-    if "verdict" in _sub_state:
-        return _sub_state["verdict"]
-    import json
-    import subprocess
-    import sys
-
-    code = (
-        "import json, sys\n"
-        "import jax\n"
-        "print(json.dumps([d.platform for d in jax.devices()]))\n"
-    )
-    ok, error = False, None
-    try:
-        res = subprocess.run(
-            [sys.executable, "-c", code],
-            capture_output=True,
-            text=True,
-            timeout=timeout_s,
-        )
-        if res.returncode == 0:
-            try:
-                platforms = json.loads(
-                    res.stdout.strip().splitlines()[-1]
-                )
-                ok = any(p != "cpu" for p in platforms)
-                if not ok:
-                    error = (
-                        f"no accelerator device (platforms={platforms})"
-                    )
-            except (ValueError, IndexError):
-                error = f"probe emitted garbage: {res.stdout[:200]!r}"
-        else:
-            error = (res.stderr or res.stdout or "probe failed")[-300:]
-    except subprocess.TimeoutExpired:
-        error = (
-            f"device probe subprocess exceeded {timeout_s:.0f}s "
-            "(backend init hung) and was killed"
-        )
-    except Exception as exc:  # noqa: BLE001 - probing must never raise
-        error = f"probe subprocess error: {exc}"[:300]
-    _sub_state["verdict"] = (ok, error)
-    return ok, error

@@ -1,4 +1,4 @@
-"""Persistent XLA compilation cache setup, shared by bench.py,
+"""Persistent XLA compilation cache setup, shared by
 tests/conftest.py, __graft_entry__.py, TPUProvider and the serve
 registry.
 

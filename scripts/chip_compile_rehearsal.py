@@ -10,16 +10,13 @@ results or run time.
     JAX_PLATFORMS=cpu python scripts/chip_compile_rehearsal.py            # main-path rows
     JAX_PLATFORMS=cpu python scripts/chip_compile_rehearsal.py --rows all # + report-only rows
     JAX_PLATFORMS=cpu python scripts/chip_compile_rehearsal.py \
-        --rows bytes:microcond:unrolled --cut 1800
+        --rows pairing8 --cut 1800
 
 The parent never imports JAX: it runs ONE child per row, one after
 another (only one process at a time may load the TPU library — do not
 run this beside a pytest run of tests/test_chip_compile.py), kills a
 child at --cut seconds, and prints one JSON object per row plus a
-markdown table.  `jax.default_backend()` still says "cpu" during a
-rehearsal, so each child steers `_kernel_variant` / `_cios_unrolled`
-through the environment variables they already read and wraps the
-function in a fresh `jax.jit`.
+markdown table.
 """
 
 from __future__ import annotations
@@ -38,18 +35,8 @@ if REPO not in sys.path:
 LANES = 4096
 KEY_BUCKET = 32  # TPUProvider.KEY_BUCKET
 
-# row name -> (program, kernel variant, CIOS form)
-MAIN_ROWS = (
-    "bytes:auto:auto",
-    "limbs:auto:auto",
-    "channels4:auto:auto",
-)
-TABLE_ROWS = (
-    "bytes:microcond:unrolled",
-    "bytes:inline:unrolled",
-    "bytes:inline:looped",
-    "bytes:microcond:looped",
-)
+# a row is one program
+MAIN_ROWS = ("bytes", "limbs", "channels4")
 REPORT_ROWS = ("mvcc5000", "pairing8")
 
 
@@ -69,8 +56,7 @@ def _memory(compiled) -> dict:
 
 
 def _lower_compile(jitted, args) -> dict:
-    """Lower and compile a FRESH jax.jit (no cached trace of another
-    variant is reused); times, memory analysis, devices it spans."""
+    """Lower and compile; times, memory analysis, devices it spans."""
     import jax
 
     t0 = time.perf_counter()
@@ -110,16 +96,8 @@ def _verify_shapes(kind: str, sharding):
     )
 
 
-def run_row(row: str) -> dict:
+def run_row(program: str) -> dict:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    program, _, rest = row.partition(":")
-    variant, _, cios = rest.partition(":")
-    if variant and variant != "auto":
-        os.environ["FABRIC_TPU_KERNEL_VARIANT"] = variant
-    if cios and cios != "auto":
-        os.environ["FABRIC_TPU_CIOS_UNROLL"] = (
-            "1" if cios == "unrolled" else "0"
-        )
 
     import jax
     import jax.numpy as jnp
@@ -141,17 +119,7 @@ def run_row(row: str) -> dict:
     from fabric_tpu.ops import bignum as bn
     from fabric_tpu.ops import p256_kernel as pk
 
-    # `auto`: what it resolves to ON A TPU (the backend here is the CPU)
-    if variant in ("", "auto"):
-        os.environ["FABRIC_TPU_KERNEL_VARIANT"] = pk._AUTO_VARIANT["tpu"]
-    if cios in ("", "auto"):
-        os.environ["FABRIC_TPU_CIOS_UNROLL"] = (
-            "1" if bn._AUTO_CIOS_UNROLLED["tpu"] else "0"
-        )
-    info = {"row": row}
-    if program in ("bytes", "limbs", "channels4"):
-        info["variant"] = pk._kernel_variant()
-        info["cios"] = "unrolled" if bn._cios_unrolled() else "looped"
+    info = {"row": program}
 
     if program == "bytes":
         info.update(
@@ -225,12 +193,11 @@ def run_row(row: str) -> dict:
         def run(w_arrs, p1x, p1y, p2x, p2y, okm):
             return pair._unity_check(w_arrs, sched_g, p1x, p1y, p2x, p2y, okm)
 
-        with bn.force_looped_cios():
-            info.update(
-                _lower_compile(jax.jit(run), (w_args, col, col, col, col, ok))
-            )
+        info.update(
+            _lower_compile(jax.jit(run), (w_args, col, col, col, col, ok))
+        )
     else:
-        raise SystemExit(f"unknown row {row!r}")
+        raise SystemExit(f"unknown row {program!r}")
     info["result"] = "compiles"
     return info
 
@@ -269,9 +236,8 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument(
         "--rows", default="main",
-        help="main | table | report | all | comma-separated row names "
-        "(program:variant:cios; programs: bytes, limbs, channels4, "
-        "mvcc5000, pairing8)",
+        help="main | report | all | comma-separated row names "
+        "(bytes, limbs, channels4, mvcc5000, pairing8)",
     )
     ap.add_argument("--cut", type=float, default=900.0,
                     help="seconds before a row's child is killed")
@@ -284,9 +250,8 @@ def main() -> int:
 
     groups = {
         "main": MAIN_ROWS,
-        "table": TABLE_ROWS,
         "report": REPORT_ROWS,
-        "all": MAIN_ROWS + TABLE_ROWS + REPORT_ROWS,
+        "all": MAIN_ROWS + REPORT_ROWS,
     }
     rows = groups.get(args.rows) or tuple(args.rows.split(","))
     results = []
@@ -295,8 +260,8 @@ def main() -> int:
         print(json.dumps(res), flush=True)
         results.append(res)
 
-    print("\n| row | variant / CIOS | lower | TPU compile | memory | result |")
-    print("| --- | --- | --- | --- | --- | --- |")
+    print("\n| row | lower | TPU compile | memory | result |")
+    print("| --- | --- | --- | --- | --- |")
     for r in results:
         mem = r.get("memory")
         mem_s = (
@@ -304,7 +269,7 @@ def main() -> int:
             f"args {mem['args_mb']} MB" if mem else "-"
         )
         print(
-            f"| {r['row']} | {r.get('variant', '-')} / {r.get('cios', '-')} "
+            f"| {r['row']} "
             f"| {r.get('lower_s', '-')} s | {r.get('compile_s', '-')} s "
             f"| {mem_s} | {r['result']} |"
         )

@@ -11,7 +11,7 @@
 # Dependency-free and import-free: fablife parses source with ast on
 # the shared toolkit chassis — it never imports the analyzed modules,
 # so this gate passes/fails identically in minimal environments (no
-# cryptography, no jax, no numpy).  Scans tests/ and bench.py too: a
+# cryptography, no jax, no numpy).  Scans tests/ too: a
 # leaked tempdir in a test helper accumulates across CI runs exactly
 # like one in the serving plane.  Runs in ~5s.
 set -uo pipefail
@@ -19,7 +19,7 @@ set -uo pipefail
 cd "$(dirname "$0")/.."
 
 timeout -k 5 60 python -m fabric_tpu.tools.fablife \
-    fabric_tpu/ tests/ bench.py
+    fabric_tpu/ tests/
 rc=$?
 
 if [ "$rc" -ne 0 ]; then

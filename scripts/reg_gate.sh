@@ -17,7 +17,7 @@ set -uo pipefail
 cd "$(dirname "$0")/.."
 
 timeout -k 5 60 python -m fabric_tpu.tools.fabreg \
-    --readme README.md fabric_tpu/ tests/ bench.py
+    --readme README.md fabric_tpu/ tests/
 rc=$?
 
 if [ "$rc" -ne 0 ]; then

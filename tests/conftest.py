@@ -26,7 +26,6 @@ requires_crypto = pytest.mark.skipif(
     reason="needs the cryptography package (X.509 / TLS material)",
 )
 
-os.environ.setdefault("FABRIC_TPU_CIOS_UNROLL", "0")
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in xla_flags:
     os.environ["XLA_FLAGS"] = (

@@ -58,26 +58,12 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.fixture
-def as_on_tpu(monkeypatch):
-    """jax.default_backend() says "cpu" during a rehearsal: steer the
-    kernel to what `auto` resolves to on a TPU, from the test."""
-    monkeypatch.setenv(
-        "FABRIC_TPU_KERNEL_VARIANT", pk._AUTO_VARIANT["tpu"]
-    )
-    monkeypatch.setenv(
-        "FABRIC_TPU_CIOS_UNROLL",
-        "1" if bn._AUTO_CIOS_UNROLLED["tpu"] else "0",
-    )
-
-
 POINT_BYTES = 3 * bn.NLIMBS * LANES * 4
 LIMBS_BYTES = bn.NLIMBS * LANES * 4
 
 
 def _compile(fn, *args):
-    # a fresh jit: no cached trace of another variant is reused; raises
-    # what the chip's compiler would raise
+    # raises what the chip's compiler would raise
     return jax.jit(fn).lower(*args).compile()
 
 
@@ -102,26 +88,24 @@ def _unstack_point(rows):
 _stack_point = fo.stack_point_rows
 
 
-def test_bytes_to_limbs_compiles(one_chip, no_persistent_cache, as_on_tpu):
+def test_bytes_to_limbs_compiles(one_chip, no_persistent_cache):
     rows = jax.ShapeDtypeStruct((LANES, 32), jnp.uint8, sharding=one_chip)
     assert _output_bytes(_compile(pk.bytes_to_limbs_device, rows)) >= LIMBS_BYTES
 
 
 def test_montgomery_multiply_compiles_in_the_chip_form(
-    one_chip, no_persistent_cache, as_on_tpu
+    one_chip, no_persistent_cache
 ):
     def mul(a, b):
         return bn.restack(bn.mont_mul_l(pk.CTX_P, bn.split(a), bn.split(b)))
 
     compiled = _compile(mul, _limbs(one_chip), _limbs(one_chip))
-    # the looped CIOS is a fori_loop -> an HLO while; the unrolled form
-    # is one flat DAG with none
-    has_loop = "while" in compiled.as_text()
-    assert has_loop == (not bn._AUTO_CIOS_UNROLLED["tpu"])
+    # the CIOS is a fori_loop -> an HLO while
+    assert "while" in compiled.as_text()
 
 
 def test_stacked_multiply_compiles_as_one_loop(
-    one_chip, no_persistent_cache, as_on_tpu
+    one_chip, no_persistent_cache
 ):
     """Six products side by side (a level of a point operation) at the real
     width: one looped CIOS over (20, 6, 4096), not six."""
@@ -137,17 +121,14 @@ def test_stacked_multiply_compiles_as_one_loop(
     compiled = _compile(mul6, rows, rows)
     text = compiled.as_text()
     assert _output_bytes(compiled) >= k * LIMBS_BYTES
-    if bn._AUTO_CIOS_UNROLLED["tpu"]:
-        assert "while" not in text
-    else:
-        # one `while` instruction, and its accumulator carries all six
-        loops = [l for l in text.splitlines() if " while(" in l]
-        assert len(loops) == 1, len(loops)
-        assert f"u32[{bn.NLIMBS},{k},{LANES}]" in loops[0]
+    # one `while` instruction, and its accumulator carries all six
+    loops = [l for l in text.splitlines() if " while(" in l]
+    assert len(loops) == 1, len(loops)
+    assert f"u32[{bn.NLIMBS},{k},{LANES}]" in loops[0]
 
 
 @pytest.mark.parametrize("op", ["point_add", "point_double"])
-def test_point_ops_compile(one_chip, no_persistent_cache, as_on_tpu, op):
+def test_point_ops_compile(one_chip, no_persistent_cache, op):
     if op == "point_add":
         def fn(p, q):
             return _stack_point(pk.point_add(_unstack_point(p), _unstack_point(q)))
@@ -160,7 +141,7 @@ def test_point_ops_compile(one_chip, no_persistent_cache, as_on_tpu, op):
 
 
 @pytest.mark.parametrize("table", ["per_lane_q", "shared_g"])
-def test_table_select_compiles(one_chip, no_persistent_cache, as_on_tpu, table):
+def test_table_select_compiles(one_chip, no_persistent_cache, table):
     shape = (16, 3, bn.NLIMBS, LANES) if table == "per_lane_q" else (16, 3, bn.NLIMBS)
     tab = jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=one_chip)
     idx = jax.ShapeDtypeStruct((LANES,), jnp.uint32, sharding=one_chip)
@@ -171,7 +152,7 @@ def test_table_select_compiles(one_chip, no_persistent_cache, as_on_tpu, table):
     assert _output_bytes(_compile(fn, tab, idx)) >= POINT_BYTES
 
 
-def test_final_comparison_compiles(one_chip, no_persistent_cache, as_on_tpu):
+def test_final_comparison_compiles(one_chip, no_persistent_cache):
     valid = jax.ShapeDtypeStruct((LANES,), jnp.bool_, sharding=one_chip)
 
     def fn(acc, r, ok):
@@ -181,7 +162,7 @@ def test_final_comparison_compiles(one_chip, no_persistent_cache, as_on_tpu):
     assert _output_bytes(compiled) >= LANES
 
 
-def test_key_gather_compiles(one_chip, no_persistent_cache, as_on_tpu):
+def test_key_gather_compiles(one_chip, no_persistent_cache):
     """The bytes program's on-device key gather: (20, K) columns of the
     distinct keys, one column index per lane."""
     cols = _limbs(one_chip, lanes=32)  # TPUProvider.KEY_BUCKET

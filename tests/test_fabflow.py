@@ -548,8 +548,7 @@ def test_repo_is_clean(repo_findings):
 
 def test_toolkit_port_changed_nothing(repo_findings):
     """The PR 11 toolkit extraction is behavior-pinned: same chassis
-    objects, same rule ids, and the repo's suppressed count exactly as
-    before the port (the one qm_term relational-underflow bet)."""
+    objects, same rule ids, and no suppression anywhere in the repo."""
     from fabric_tpu.tools import toolkit
 
     assert fabflow.Finding is toolkit.Finding
@@ -559,7 +558,7 @@ def test_toolkit_port_changed_nothing(repo_findings):
         "limb-overflow", "mask-fail-open",
     ]
     _findings, stats = repo_findings
-    assert stats["suppressed"] == 1
+    assert stats["suppressed"] == 0
     collected = []
     fabflow.analyze_sources(
         {
@@ -580,7 +579,6 @@ def test_toolkit_port_changed_nothing(repo_findings):
 def test_repo_suppressions_state_computed_bounds(repo_findings):
     _, stats = repo_findings
     reasons = fabflow.suppression_reasons([str(REPO_ROOT / "fabric_tpu")])
-    assert len(reasons) >= 1  # the qm_term relational-underflow bet
     for path, line, rules, reason in reasons:
         assert reason, f"{path}:{line}: suppression without a reason"
         assert re.search(r"\d", reason), (
@@ -644,7 +642,37 @@ def test_bignum_cios_proof_holds_standalone():
         rule_ids=["limb-overflow", "dtype-narrowing", "float-contamination"],
     )
     assert findings == []
-    assert stats["suppressed"] == 1  # qm_term's documented relational bet
+    assert stats["suppressed"] == 0
+
+
+_CIOS_STEP = "nt = t[1:] + ai * b_s[1:] + q * m_s[1:]\n"
+
+
+@pytest.mark.parametrize(
+    "extra_products,fires", [(0, False), (1, False), (2, True)]
+)
+def test_bignum_cios_proof_reaches_the_loop_body(extra_products, fires):
+    """The proof above is about the loop, not about nothing: the body of
+    `mont_mul_l`'s `lax.fori_loop` reads its operands through a closure,
+    and fabflow must follow them there.  The real source with one more
+    product a step still fits (~0.94 * 2^32); with two it must fire, on
+    the lines of the loop body."""
+    path = REPO_ROOT / "fabric_tpu" / "ops" / "bignum.py"
+    src = path.read_text(encoding="utf-8")
+    assert src.count(_CIOS_STEP) == 1
+    widened = _CIOS_STEP.rstrip("\n") + " + ai * b_s[1:]" * extra_products
+    src = src.replace(_CIOS_STEP, widened + "\n")
+    findings, _ = fabflow.analyze_source(
+        src, "fabric_tpu/ops/bignum.py", ["limb-overflow"]
+    )
+    if not fires:
+        assert findings == []
+        return
+    assert rule_ids(findings) and set(rule_ids(findings)) == {"limb-overflow"}
+    lines = src.splitlines()
+    start = next(i for i, l in enumerate(lines, 1) if "def body(i, t):" in l)
+    end = next(i for i, l in enumerate(lines, 1) if "lax.fori_loop(" in l and i > start)
+    assert all(start < f.line < end for f in findings), [f.line for f in findings]
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,7 @@ the pre-PR-8 unclamped ``retry_after_ms`` sleep fires
 ``wire-unclamped`` — the fixed shapes are the negative controls),
 suppression semantics, loud pairs.toml parse errors, CLI plumbing, the
 toolkit analyzer-registry protocol, and the repo self-check (the CI
-gate invariant: ``fablife fabric_tpu/ tests/ bench.py`` reports 0
+gate invariant: ``fablife fabric_tpu/ tests/`` reports 0
 unsuppressed findings).
 
 Fixture code lives in *strings* on purpose: the repo self-check scans
@@ -892,7 +892,6 @@ def test_repo_has_zero_unsuppressed_findings():
         [
             str(REPO_ROOT / "fabric_tpu"),
             str(REPO_ROOT / "tests"),
-            str(REPO_ROOT / "bench.py"),
         ]
     )
     assert findings == [], "\n".join(

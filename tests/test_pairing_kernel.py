@@ -12,7 +12,6 @@ import numpy as np
 import pytest
 
 from fabric_tpu.crypto import fp256bn as host
-from fabric_tpu.ops import bignum as bn
 from fabric_tpu.ops import fp12 as f12
 
 RNG = random.Random(20260731)
@@ -54,26 +53,25 @@ def like2():
 
 def test_tower_ops_bit_exact():
     x, y = rand_fp12(), rand_fp12()
-    with bn.force_looped_cios():
-        lk = like2()
+    lk = like2()
 
-        @jax.jit
-        def fn(x_st, y_st):
-            xx = f12.unpack(x_st)
-            yy = f12.unpack(y_st)
-            return (
-                f12.pack(f12.fp12_mul(xx, yy)),
-                f12.pack(f12.fp12_sqr(xx)),
-                f12.pack(f12.fp12_frobenius(xx, 1)),
-                f12.pack(f12.fp12_frobenius(xx, 2)),
-                f12.pack(f12.fp12_conj(xx)),
-            )
-
-        outs = fn(
-            f12.pack(f12.fp12_from_host(x, lk)),
-            f12.pack(f12.fp12_from_host(y, lk)),
+    @jax.jit
+    def fn(x_st, y_st):
+        xx = f12.unpack(x_st)
+        yy = f12.unpack(y_st)
+        return (
+            f12.pack(f12.fp12_mul(xx, yy)),
+            f12.pack(f12.fp12_sqr(xx)),
+            f12.pack(f12.fp12_frobenius(xx, 1)),
+            f12.pack(f12.fp12_frobenius(xx, 2)),
+            f12.pack(f12.fp12_conj(xx)),
         )
-        got = [f12.fp12_to_host(f12.unpack(np.asarray(o))) for o in outs]
+
+    outs = fn(
+        f12.pack(f12.fp12_from_host(x, lk)),
+        f12.pack(f12.fp12_from_host(y, lk)),
+    )
+    got = [f12.fp12_to_host(f12.unpack(np.asarray(o))) for o in outs]
     assert got[0] == host.fp12_mul(x, y)
     assert got[1] == host.fp12_sqr(x)
     assert got[2] == host.fp12_frobenius(x, 1)
@@ -85,19 +83,18 @@ def test_tower_ops_bit_exact():
 def test_inv_and_pow_bit_exact():
     x = rand_fp12()
     e = 0xDEADBEEF12345
-    with bn.force_looped_cios():
-        lk = like2()
+    lk = like2()
 
-        @jax.jit
-        def fn(x_st):
-            xx = f12.unpack(x_st)
-            return (
-                f12.pack(f12.fp12_inv(xx)),
-                f12.pack(f12.fp12_pow_const(xx, e)),
-            )
+    @jax.jit
+    def fn(x_st):
+        xx = f12.unpack(x_st)
+        return (
+            f12.pack(f12.fp12_inv(xx)),
+            f12.pack(f12.fp12_pow_const(xx, e)),
+        )
 
-        outs = fn(f12.pack(f12.fp12_from_host(x, lk)))
-        got = [f12.fp12_to_host(f12.unpack(np.asarray(o))) for o in outs]
+    outs = fn(f12.pack(f12.fp12_from_host(x, lk)))
+    got = [f12.fp12_to_host(f12.unpack(np.asarray(o))) for o in outs]
     assert got[0] == host.fp12_inv(x)
     assert got[1] == host.fp12_pow(x, e)
 

@@ -1,7 +1,7 @@
 """North-star-shaped scale test: a 1,000-tx block with real envelopes
 and signatures through the full channel commit pipeline (parse ->
 validate -> MVCC -> sqlite commit), the in-suite version of BASELINE
-config #2 (bench.py measures the same shape on the accelerator)."""
+config #2."""
 
 import pytest
 
