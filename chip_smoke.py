@@ -525,9 +525,8 @@ def phase_serve(world, chain, tmp, seed, want_bucket, obs, compiles, sent_before
     rng = random.Random(f"{seed}:serve")
     sw = SoftwareProvider()
     before = set(threading.enumerate())
-    # warm_ladder="off" and no warm(): the ladder compiles a second (limb)
-    # program the serving path never calls, and warm()'s 8-lane batch
-    # would compile the 128-lane bucket — bring-up pays for neither
+    # warm_ladder="off" and no warm(): the first request compiles the one
+    # bucket bring-up serves
     address = os.path.join(tmp, "serve.sock")
     if len(address.encode()) > 100:  # AF_UNIX sun_path holds 108 bytes
         address = "127.0.0.1:0"

@@ -124,6 +124,11 @@ CANONICAL_METRICS: Tuple[MetricSpec, ...] = (
         "parallel/batcher.py _run",
     ),
     MetricSpec(
+        "fabric_batcher_launches_by_bucket_total", "counter", ("bucket",),
+        "provider launches by the lane width the provider padded them to",
+        "parallel/batcher.py _run",
+    ),
+    MetricSpec(
         "fabric_batcher_busy_rejects_total", "counter", (),
         "try_submit admissions rejected (ST_BUSY backpressure)",
         "parallel/batcher.py _admit",

@@ -357,6 +357,22 @@ class TPUProvider(Provider):
             ky_mat[:, : len(ky_cols)] = np.stack(ky_cols, axis=1)
         return (e_bytes, r_bytes, s_bytes, kx_mat, ky_mat, idx, ok), None
 
+    @staticmethod
+    def bucket_for(lanes: int) -> int:
+        """The lane width a device launch of ``lanes`` lanes is padded to."""
+        return _bucket(lanes)
+
+    def warm_bucket(self, lanes: int) -> None:
+        """Trace, lower and compile (or load) the program a launch of
+        ``lanes`` lanes dispatches, at its bucket, and run it once on dead
+        lanes: a later launch of that width compiles nothing, since it
+        calls the same jitted program with arguments of the same shapes.
+        Counts no verified lane."""
+        prep, _ = self.prep_bytes(
+            [None] * lanes, [b""] * lanes, [bytes(32)] * lanes
+        )
+        np.asarray(self._dispatch_bytes_or_fallback(prep))
+
     def _dispatch_bytes(self, prep):
         e_bytes, r_bytes, s_bytes, kx, ky, idx, ok = prep
         n = ok.shape[0]

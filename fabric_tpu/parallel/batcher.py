@@ -13,8 +13,15 @@ endorsement-path singles).  This batcher sits between them:
   whatever is queued, lingers a few ms for stragglers while the bucket
   is small, then launches ONE device program for the whole batch via
   the provider's async path (overlapping host prep of the next batch
-  with device execution of the current one, like the P4 pipeline);
-- each request gets a resolver (future) for exactly its lanes.
+  with device execution of the current one, like the P4 pipeline); a
+  batch never grows past ``max_launch_lanes`` (a sidecar's widest warm
+  program), nor takes a request that would pad it wider than that
+  request's own launch would be: the request leads the next launch;
+- a settler thread waits on the launches in flight, oldest first, and
+  hands each request exactly its lanes (its resolver is a future), so
+  the dispatcher never waits on the device: requests that arrive while
+  a launch runs are dispatched behind it, and the device is not left
+  idle while its last launch's verdicts go out.
 
 Coalescing across channels keeps lanes/launch high even when individual
 blocks are small — the multi-channel aggregate (BASELINE config #5)
@@ -45,7 +52,8 @@ import os
 import queue
 import threading
 import time
-from typing import Callable, List, Optional, Sequence, Tuple
+from collections import deque
+from typing import Callable, Deque, List, Optional, Sequence, Tuple
 
 from fabric_tpu.common import fabobs
 from fabric_tpu.common.faults import fault_point
@@ -114,10 +122,24 @@ class VerifyBatcher:
         max_pending_lanes: int = 65536,
         dispatch_retry: Optional[RetryPolicy] = None,
         join_timeout_s: float = 10.0,
+        max_launch_lanes: Optional[int] = None,
     ):
         self.provider = provider
         self.max_batch = max_batch
         self.linger_s = linger_s
+        # the widest launch the provider has a warm program for (a
+        # sidecar's largest bucket): a request that would take a launch
+        # past it waits for the next one.  None: no cap
+        self.max_launch_lanes = max_launch_lanes
+        # the request that did not fit the last launch; only the
+        # dispatcher thread reads or writes it
+        self._held: Optional[_Request] = None
+        # launches dispatched and not yet settled, oldest first: (requests,
+        # resolver, dispatch time, lanes, launch span); None is the
+        # settler's exit token.  The settler pops an entry only once it
+        # is settled, so len() counts the launches in flight
+        self._pending: Deque[Optional[Tuple]] = deque()
+        self._pending_cv = threading.Condition()
         # stop()'s patience for the dispatcher thread before settling
         # stragglers fail-closed (shorten in tests with hung resolvers)
         self.join_timeout_s = join_timeout_s
@@ -147,11 +169,8 @@ class VerifyBatcher:
             os.environ.get("FABRIC_TPU_BATCHER_RTT_MS", "25")
         )
         self.rtt_ema_ms: Optional[float] = None
-        # today _observe_rtt runs only on the dispatcher thread (every
-        # _settle call site is inside _run); the lock pins the EWMA
-        # read-modify-write as the invariant rather than an accident of
-        # the current call graph, so a future settle-from-elsewhere
-        # cannot silently introduce the race
+        # _observe_rtt runs on the settler thread while the dispatcher
+        # reads the mode; the lock pins the EWMA read-modify-write
         self._rtt_lock = threading.Lock()
         # probe only launches small enough that device compute is
         # negligible next to transport RTT even on an attached chip
@@ -159,6 +178,11 @@ class VerifyBatcher:
         # coalesced launch is ~30ms of COMPUTE and would mis-flip a
         # low-RTT chip into passthrough)
         self.RTT_PROBE_LANES = 64
+        self._settler = threading.Thread(
+            target=self._settle_loop, name="verify-batcher-settle",
+            daemon=True,
+        )
+        self._settler.start()
         self._thread = threading.Thread(
             target=self._run, name="verify-batcher", daemon=True
         )
@@ -293,7 +317,9 @@ class VerifyBatcher:
 
     # -- dispatcher ------------------------------------------------------
     def _take_batch(self) -> Optional[List[_Request]]:
-        first = self._q.get()  # fablife: disable=blocking-unbudgeted  # the dispatcher's idle park, not a request hop: stop() posts the None sentinel this get() returns on, after settling in-flight work fail-closed
+        first, self._held = self._held, None
+        if first is None:
+            first = self._q.get()  # fablife: disable=blocking-unbudgeted  # the dispatcher's idle park, not a request hop: stop() posts the None sentinel this get() returns on, after settling in-flight work fail-closed
         if first is None:
             return None
         batch = [first]
@@ -303,6 +329,7 @@ class VerifyBatcher:
             # request, overlapping in flight (admission control already
             # happened at submit)
             return batch
+        t_first = time.perf_counter()
         waiter = (
             threading.Event()
         )  # fresh event as a precise, interruptible sleep
@@ -328,23 +355,29 @@ class VerifyBatcher:
                 try:
                     nxt = self._q.get_nowait()
                 except queue.Empty:
+                    if linger > 0 and self._backlogged(lanes):
+                        continue  # linger on while the device is busy
                     break
             if nxt is None:
                 self._q.put(None)  # re-post the stop token
                 break
+            if not self._fits(lanes, len(nxt.keys)):
+                self._held = nxt  # first of the next launch
+                break
             batch.append(nxt)
             lanes += len(nxt.keys)
+        # the wait for company after the batch's first request
+        fabobs.obs_record_span(
+            "batcher.linger", t_first, time.perf_counter(),
+            parent=first.parent, joined=len(batch) - 1,
+        )
         return batch
 
     def _run(self) -> None:
-        # entries: (requests, resolver, dispatch_time, lanes, launch span)
-        pending: List[Tuple] = []
         while True:
             batch = self._take_batch()
             if batch is None:
-                for entry in pending:
-                    self._settle(*entry)
-                return
+                break
             keys: List = []
             sigs: List[bytes] = []
             digests: List[bytes] = []
@@ -370,10 +403,12 @@ class VerifyBatcher:
                     "batcher.queue_wait", r.t_submit, t_launch,
                     parent=r.parent,
                 )
+            with self._pending_cv:
+                inflight = len(self._pending)
             launch_span = fabobs.span(
                 "batcher.launch",
                 parent=batch[0].parent if len(batch) == 1 else None,
-                lanes=len(keys), requests=len(batch),
+                lanes=len(keys), requests=len(batch), inflight=inflight,
             )
             if launch_span.span_id and len(batch) > 1:
                 launch_span.set(req_ids=[
@@ -386,32 +421,81 @@ class VerifyBatcher:
             except BaseException as exc:  # fablint: disable=broad-except  # error propagated to every waiting caller via r.error
                 for r in batch:
                     self._settle_error(r, exc)
-                if self._q.empty():
-                    # mirror the success path's idle drain: without it,
-                    # earlier launches still in `pending` would strand
-                    # their resolvers behind the blocking q.get() until
-                    # unrelated traffic (or stop) arrived
-                    while pending:
-                        self._settle(*pending.pop(0))
                 continue
             self.launches += 1
             self.lanes += len(keys)
             fabobs.obs_count("fabric_batcher_launches_total", mode=self.mode)
-            fabobs.obs_observe("fabric_batcher_batch_lanes", len(keys))
-            pending.append(
-                (batch, resolver, time.perf_counter(), len(keys), launch_span)
+            fabobs.obs_count(
+                "fabric_batcher_launches_by_bucket_total",
+                bucket=str(self._bucket_of(len(keys))),
             )
-            # depth-4 pipeline: keep up to three launches in flight before
-            # settling the oldest — where per-launch latency is high,
-            # serializing launches costs more than coalescing
-            # saves, so small batches overlap like independent callers
-            # would while large ones still coalesce
-            while len(pending) > 3:
-                self._settle(*pending.pop(0))
-            if self._q.empty():
-                # idle: drain so callers aren't left waiting on us
-                while pending:
-                    self._settle(*pending.pop(0))
+            fabobs.obs_observe("fabric_batcher_batch_lanes", len(keys))
+            with self._pending_cv:
+                self._pending.append(
+                    (batch, resolver, time.perf_counter(), len(keys),
+                     launch_span)
+                )
+                self._pending_cv.notify_all()
+                # depth-4 pipeline: up to four launches in flight; the
+                # dispatcher takes its next batch once the settler has
+                # brought them back to three
+                while len(self._pending) > 3:
+                    self._pending_cv.wait()  # fablife: disable=blocking-unbudgeted  # the settler pops and notifies after every settle; a hung resolver holds it, and stop() then settles the stragglers fail-closed after its join timeout
+        # stop: the settler settles what is in flight, then exits (a
+        # hung resolver holds it past the join; stop() then settles the
+        # stragglers fail-closed)
+        with self._pending_cv:
+            self._pending.append(None)
+            self._pending_cv.notify_all()
+        self._settler.join(timeout=self.join_timeout_s)
+
+    def _settle_loop(self) -> None:
+        """Settle the launches in flight in dispatch order, each as soon
+        as its resolver returns, until the dispatcher's exit token."""
+        while True:
+            with self._pending_cv:
+                while not self._pending:
+                    self._pending_cv.wait()  # fablife: disable=blocking-unbudgeted  # the settler's idle park: every dispatch, and the dispatcher's exit on stop(), appends and notifies
+                entry = self._pending[0]
+            if entry is None:
+                return
+            self._settle(*entry)
+            with self._pending_cv:
+                self._pending.popleft()
+                self._pending_cv.notify_all()
+
+    def _backlogged(self, lanes: int) -> bool:
+        """Whether the launches in flight are at least twice as wide as a
+        launch of ``lanes``: the device runs launches one after another,
+        so it has that work ahead of this batch, which may wait for
+        company at no cost to it.  A provider that pads to no buckets
+        (a host pool) runs launches side by side: no batch waits there."""
+        if getattr(self.provider, "bucket_for", None) is None:
+            return False
+        with self._pending_cv:
+            ahead = [e[3] for e in self._pending if e is not None]
+        return sum(map(self._bucket_of, ahead)) >= 2 * self._bucket_of(lanes)
+
+    def _fits(self, lanes: int, more: int) -> bool:
+        """Whether a launch of ``lanes`` lanes may take a request of
+        ``more``: it stays within ``max_launch_lanes``, and the width
+        the provider pads it to grows by no more than the request's own
+        launch would take.  The program's time is linear in its width
+        (the kernel on TPU v5e, 2,048 to 8,192 lanes), so past that the
+        request verifies in less device time leading the next launch."""
+        if (
+            self.max_launch_lanes is not None
+            and lanes + more > self.max_launch_lanes
+        ):
+            return False
+        grown = self._bucket_of(lanes + more) - self._bucket_of(lanes)
+        return grown <= self._bucket_of(more)
+
+    def _bucket_of(self, lanes: int) -> int:
+        """The width the provider pads a launch of ``lanes`` to; a
+        provider that pads nothing launches at the width it is given."""
+        bucket_for = getattr(self.provider, "bucket_for", None)
+        return lanes if bucket_for is None else bucket_for(lanes)
 
     def _launch(self, keys: List, sigs: List[bytes], digests: List[bytes]):
         """One device/provider launch with bounded transient retry: a

@@ -22,11 +22,13 @@ Every rung is accounted per bucket (``stats()``): compile wall ms,
 whether the AOT artifact hit, how many XLA compile events fired — the
 numbers the warm-restart test asserts on.
 
-The registry is engine-generic: production wires the ECDSA limb kernel
-(``ops.p256_kernel.verify_batch_device``); the CI-able ladder wires
-:func:`demo_limb_program` (a real ``ops.bignum`` Montgomery
-exponentiation — the same limb code path, a graph small enough to
-compile in seconds on the 2-vCPU gate box).
+The registry is engine-generic: the sidecar's ``verify`` ladder has the
+provider build its own program at each bucket (through the provider's jit
+and compile cache, so serving calls the very executable the warm-up
+built); the CI-able ladder wires :func:`demo_limb_program` (a real
+``ops.bignum`` Montgomery exponentiation — the same limb code path, a
+graph small enough to compile in seconds on the 2-vCPU gate box) through
+:meth:`for_jax_program`.
 
 jax is imported lazily and only inside methods — importing this module
 costs nothing in jax-free processes (fablint module-import discipline).
@@ -437,22 +439,3 @@ def demo_limb_program():
         return (jax.ShapeDtypeStruct((bn.NLIMBS, bucket), jnp.uint32),)
 
     return fn, shapes_for
-
-
-def verify_limb_program():
-    """(fn, shapes_for) for the REAL device program: the batched ECDSA
-    limb-matrix verify kernel.  Minutes of XLA compile cold (NOTES_BUILD)
-    — which is the whole point of warming it once in a resident process
-    and serializing the executable."""
-    import jax
-    import jax.numpy as jnp
-
-    from fabric_tpu.ops import bignum as bn
-    from fabric_tpu.ops.p256_kernel import verify_batch_device
-
-    def shapes_for(bucket: int):
-        limbs = jax.ShapeDtypeStruct((bn.NLIMBS, bucket), jnp.uint32)
-        ok = jax.ShapeDtypeStruct((bucket,), jnp.bool_)
-        return (limbs, limbs, limbs, limbs, limbs, ok)
-
-    return verify_batch_device, shapes_for

@@ -60,7 +60,6 @@ from fabric_tpu.serve.registry import (
     BucketProgramRegistry,
     DEFAULT_BUCKETS,
     demo_limb_program,
-    verify_limb_program,
 )
 
 logger = must_get_logger("serve.server")
@@ -318,10 +317,13 @@ class SidecarServer:
             self.provider, self.engine = provider, engine
         else:
             self.provider, self.engine = build_provider(engine)
+        # no launch wider than the widest bucket: the widest program
+        # warm() builds
         self.batcher = VerifyBatcher(
             self.provider,
             max_pending_lanes=max_pending_lanes,
             linger_s=linger_s,
+            max_launch_lanes=max(self.buckets),
         )
         self.max_pending_lanes = max_pending_lanes
         self.retry_after_base_ms = retry_after_base_ms
@@ -364,24 +366,28 @@ class SidecarServer:
     # -- warm-up -----------------------------------------------------------
     def warm(self) -> Dict:
         """Pre-warm before accepting traffic: spin the host pools with
-        one small batch, and AOT-warm the jax bucket ladder when asked.
-        Returns the warm report (bench's ``configs.serve.warm``)."""
+        one small batch, and warm the bucket ladder when asked: ``verify``
+        builds the program the provider serves with, at every bucket
+        (a launch of any of those widths then compiles nothing);
+        ``demo`` AOT-compiles the CI ladder.  Returns the warm report
+        (bench's ``configs.serve.warm``)."""
         t0 = time.perf_counter()
         report: Dict = {"engine": self.engine, "ladder": self.warm_ladder}
         report["host_warm_ms"] = round(self._warm_host() * 1000.0, 1)
         if self.warm_ladder != "off":
-            fn, shapes_for = (
-                demo_limb_program()
-                if self.warm_ladder == "demo"
-                else verify_limb_program()
-            )
-            self.registry = BucketProgramRegistry.for_jax_program(
-                fn,
-                shapes_for,
-                buckets=self.buckets,
-                label=f"serve-{self.warm_ladder}",
-                aot_dir=self.aot_dir,
-            )
+            if self.warm_ladder == "demo":
+                fn, shapes_for = demo_limb_program()
+                self.registry = BucketProgramRegistry.for_jax_program(
+                    fn,
+                    shapes_for,
+                    buckets=self.buckets,
+                    label="serve-demo",
+                    aot_dir=self.aot_dir,
+                )
+            else:
+                self.registry = BucketProgramRegistry(
+                    self.buckets, self._warm_served, label="serve-verify",
+                )
             self.registry.warm()
             report["per_bucket"] = {
                 str(k): v for k, v in self.registry.warm_report.items()
@@ -415,9 +421,25 @@ class SidecarServer:
                 1.0 if rep.get("aot_hit") else 0.0, bucket=str(bucket),
             )
 
+    def _warm_served(self, bucket: int) -> Tuple[Callable, Dict]:
+        """The ``verify`` ladder's builder: the provider compiles (or
+        loads) its own program at ``bucket`` and runs it once, so a launch
+        of that width compiles nothing.  Requests reach the program through
+        the batcher and the provider, never through the registry's table:
+        the registry keeps the warm accounting, readiness and the bucket
+        ladder.  A host engine has nothing to compile."""
+        warm = getattr(self.provider, "warm_bucket", None)
+        if warm is None:
+            return self.provider.batch_verify, {"program": "none"}
+        warm(bucket)
+        return self.provider.batch_verify, {"program": "served"}
+
     def _warm_host(self) -> float:
         """One tiny batch through the provider so pool spin-up and key
-        tables are paid before the first real request."""
+        tables are paid before the first real request.  It goes to the
+        provider's ``batch_verify``, past the batcher: under the device
+        provider's small-batch floor it stays on the host, where through
+        the batcher it would compile a device program no request uses."""
         from fabric_tpu.crypto.bccsp import ECDSAPublicKey, ec_backend
 
         t0 = time.perf_counter()
@@ -432,7 +454,7 @@ class SidecarServer:
         sig = _der.marshal_signature(r, s)
         key = ECDSAPublicKey(*kp.pub)
         n = 8
-        mask = self.batcher.verify_batch([key] * n, [sig] * n, [digest] * n)
+        mask = self.provider.batch_verify([key] * n, [sig] * n, [digest] * n)
         if list(mask) != [True] * n:
             raise RuntimeError("warm-up batch failed verification")
         return time.perf_counter() - t0
@@ -1129,14 +1151,16 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     )
     ap.add_argument(
         "--warm", default="off", choices=WARM_LADDERS,
-        help="jax bucket ladder to pre-warm: 'verify' = the real ECDSA "
-        "limb kernel (minutes cold), 'demo' = the CI-able ops.bignum "
-        "exponentiation ladder, 'off' = host warm-up only",
+        help="bucket ladder to pre-warm: 'verify' = the program the "
+        "engine serves with, at every bucket (minutes cold on a device), "
+        "'demo' = the CI-able ops.bignum exponentiation ladder, 'off' = "
+        "host warm-up only",
     )
     ap.add_argument(
         "--aot-dir", default="",
-        help="directory for serialized AOT executables (warm restarts "
-        "skip trace AND compile); empty = persistent compile cache only",
+        help="directory for the demo ladder's serialized AOT executables "
+        "(warm restarts skip trace AND compile); empty = persistent "
+        "compile cache only",
     )
     ap.add_argument("--max-pending-lanes", type=int, default=65536)
     ap.add_argument("--linger-ms", type=float, default=2.0)

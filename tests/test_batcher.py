@@ -395,10 +395,12 @@ def test_stop_wakes_admission_blocked_submitter():
     b = VerifyBatcher(
         prov, linger_s=0.0, max_pending_lanes=2, join_timeout_s=0.2
     )
-    # dispatched immediately (permits released at dispatch), then the
-    # dispatcher wedges inside the resolver
-    b.submit([b"ok", b"ok"], [b"s"] * 2, [b"d"] * 2)
-    time.sleep(0.05)
+    # four launches dispatched one by one (permits released at dispatch),
+    # each wedged in its resolver: the pipeline is full and the
+    # dispatcher waits for the settler, which waits on the first
+    for _ in range(4):
+        b.submit([b"ok", b"ok"], [b"s"] * 2, [b"d"] * 2)
+        time.sleep(0.05)
     # queued behind the wedge: holds both permits
     b.submit([b"ok", b"ok"], [b"s"] * 2, [b"d"] * 2)
 

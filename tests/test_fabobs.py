@@ -882,7 +882,8 @@ def test_device_rung_seconds_leave_out_the_wait_to_be_resolved():
 
 REQUEST_SPANS = (
     "client.encode", "client.roundtrip", "client.decode", "serve.decode",
-    "serve.verify", "serve.reply", "batcher.queue_wait", "batcher.launch",
+    "serve.verify", "serve.reply", "batcher.queue_wait", "batcher.linger",
+    "batcher.launch",
     "tpu.prep", "tpu.dispatch", "batcher.settle", "tpu.resolve",
 )
 
@@ -933,6 +934,8 @@ def test_one_sidecar_request_yields_its_spans_under_one_req_id(tmp_path):
         # the batcher's spans, on the dispatcher thread, hang under the
         # request's serve.verify; the provider's under the batcher's
         assert parent["batcher.queue_wait"] == ident["serve.verify"]
+        assert parent["batcher.linger"] == ident["serve.verify"]
+        assert mine["batcher.linger"]["args"]["joined"] == 0
         assert parent["batcher.launch"] == ident["serve.verify"]
         assert parent["batcher.settle"] == ident["batcher.launch"]
         assert parent["tpu.prep"] == parent["tpu.dispatch"] == ident["batcher.launch"]
