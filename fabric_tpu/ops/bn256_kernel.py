@@ -43,6 +43,7 @@ FIELD = fo.Field(CTX_Q)
 FE = fo.FE
 fe = fo.Field.fe
 fe_mul = FIELD.mul
+fe_mul_many = FIELD.mul_many
 fe_add = FIELD.add
 fe_sub = FIELD.sub
 fe_norm = FIELD.norm
@@ -53,71 +54,59 @@ _B3_FE = FE(bn.const_l(B3_MONT), 1)
 
 
 def point_add(p: Point, q: Point) -> Point:
-    """Complete addition, RCB 2016 algorithm 7 (a = 0)."""
+    """Complete addition, RCB 2016 algorithm 7 (a = 0).  Its 14 products
+    fall into three levels of 6, 2 and 6 independent ones; each level is
+    one `fe_mul_many`, so the addition is 3 looped-CIOS calls, not 14."""
     x1, y1, z1 = p
     x2, y2, z2 = q
     b3 = _B3_FE
 
-    t0 = fe_mul(x1, x2)
-    t1 = fe_mul(y1, y2)
-    t2 = fe_mul(z1, z2)
-    t3 = fe_add(x1, y1)
-    t4 = fe_add(x2, y2)
-    t3 = fe_mul(t3, t4)
-    t4 = fe_add(t0, t1)
-    t3 = fe_sub(t3, t4)
-    t4 = fe_add(y1, z1)
-    x3 = fe_add(y2, z2)
-    t4 = fe_mul(t4, x3)
-    x3 = fe_add(t1, t2)
-    t4 = fe_sub(t4, x3)
-    x3 = fe_add(x1, z1)
-    y3 = fe_add(x2, z2)
-    x3 = fe_mul(x3, y3)
-    y3 = fe_add(t0, t2)
-    y3 = fe_sub(x3, y3)
+    t0, t1, t2, t3, t4, x3 = fe_mul_many([
+        (x1, x2),
+        (y1, y2),
+        (z1, z2),
+        (fe_add(x1, y1), fe_add(x2, y2)),
+        (fe_add(y1, z1), fe_add(y2, z2)),
+        (fe_add(x1, z1), fe_add(x2, z2)),
+    ])
+    t3 = fe_sub(t3, fe_add(t0, t1))
+    t4 = fe_sub(t4, fe_add(t1, t2))
+    y3 = fe_sub(x3, fe_add(t0, t2))
     x3 = fe_add(t0, t0)
     t0 = fe_add(x3, t0)  # bound 3
-    t2 = fe_mul(b3, t2)
+
+    t2, y3 = fe_mul_many([(b3, t2), (b3, y3)])
     z3 = fe_add(t1, t2)  # bound 2
     t1 = fe_sub(t1, t2)
-    y3 = fe_mul(b3, y3)
-    x3 = fe_mul(t4, y3)
-    t2 = fe_mul(t3, t1)
+
+    x3, t2, y3, t1, t0, z3 = fe_mul_many([
+        (t4, y3), (t3, t1), (y3, t0), (t1, z3), (t0, t3), (z3, t4),
+    ])
     x3 = fe_sub(t2, x3)
-    y3 = fe_mul(y3, fe_norm(t0))
-    t1 = fe_mul(t1, fe_norm(z3))
-    y3 = fe_add(t1, y3)
-    t0 = fe_mul(fe_norm(t0), t3)
-    z3 = fe_mul(fe_norm(z3), t4)
+    y3 = fe_add(t1, y3)  # bound 2
     z3 = fe_add(z3, t0)  # bound 2
     return Point(x3, fe_norm(y3), fe_norm(z3))
 
 
 def point_double(p: Point) -> Point:
-    """Complete doubling, RCB 2016 algorithm 9 (a = 0)."""
+    """Complete doubling, RCB 2016 algorithm 9 (a = 0): 9 products in
+    three levels of 4, 1 and 4."""
     x, y, z = p
     b3 = _B3_FE
 
-    t0 = fe_mul(y, y)
+    t0, yz, t2, xy = fe_mul_many([(y, y), (y, z), (z, z), (x, y)])
     z3 = fe_add(t0, t0)
     z3 = fe_add(z3, z3)
     z3 = fe_add(z3, z3)  # bound 8
-    z3 = fe_norm(z3)
-    t1 = fe_mul(y, z)
-    t2 = fe_mul(z, z)
+
     t2 = fe_mul(b3, t2)
-    x3 = fe_mul(t2, z3)
-    y3 = fe_add(t0, t2)
-    z3 = fe_mul(t1, z3)
+    y3 = fe_add(t0, t2)  # bound 2
     t1 = fe_add(t2, t2)
-    t2 = fe_add(t1, t2)  # bound 3
-    t0 = fe_sub(t0, t2)
-    y3 = fe_mul(t0, fe_norm(y3))
+    t0 = fe_sub(t0, fe_add(t1, t2))
+
+    x3, z3, y3, t1 = fe_mul_many([(t2, z3), (yz, z3), (t0, y3), (t0, xy)])
     y3 = fe_add(x3, y3)
-    t1 = fe_mul(x, y)
-    x3 = fe_mul(t0, t1)
-    x3 = fe_add(x3, x3)
+    x3 = fe_add(t1, t1)
     return Point(fe_norm(x3), fe_norm(y3), z3)
 
 

@@ -3,6 +3,7 @@
 
 import secrets
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -20,42 +21,123 @@ def rand_point():
     return host.g1_mul(host.G1_GEN, rand_scalar())
 
 
-class TestPointOps:
-    def test_add_matches_host(self):
-        ps = [rand_point() for _ in range(4)] + [None, host.G1_GEN]
-        qs = [rand_point() for _ in range(4)] + [host.G1_GEN, host.G1_GEN]
-        a = bk.pack_points(ps)
-        b = bk.pack_points(qs)
+def _rows_to_point(rows):
+    return bk.Point(
+        bk.fe(bn.split(rows[0])), bk.fe(bn.split(rows[1])), bk.fe(bn.split(rows[2]))
+    )
 
-        import jax
+
+def _point_to_rows(r):
+    return jnp.stack(
+        [
+            bn.restack(bk.fe_norm(r.x).limbs),
+            bn.restack(bk.fe_norm(r.y).limbs),
+            bn.restack(bk.fe_norm(r.z).limbs),
+        ]
+    )
+
+
+class TestPointOps:
+    """The complete formulas' edge cases ride in the lanes of one batch a
+    formula (6 lanes for the addition, 3 for the doubling): one XLA:CPU
+    compile each, whatever cases the lanes hold."""
+
+    def test_add_matches_host(self):
+        p, q = rand_point(), rand_point()
+        cases = {
+            "P+Q": (rand_point(), rand_point()),
+            "P+P": (p, p),
+            "P+(-P)": (q, host.g1_neg(q)),
+            "O+O": (None, None),
+            "O+P": (None, host.G1_GEN),
+            "P+O": (p, None),
+        }
+        want = {
+            "P+P": host.g1_mul(p, 2),
+            "P+(-P)": None,
+            "O+O": None,
+            "O+P": host.G1_GEN,
+            "P+O": p,
+        }
+        a = bk.pack_points([pq[0] for pq in cases.values()])
+        b = bk.pack_points([pq[1] for pq in cases.values()])
 
         @jax.jit
         def add(a, b):
-            p = bk.Point(bk.fe(bn.split(a[0])), bk.fe(bn.split(a[1])), bk.fe(bn.split(a[2])))
-            q = bk.Point(bk.fe(bn.split(b[0])), bk.fe(bn.split(b[1])), bk.fe(bn.split(b[2])))
-            r = bk.point_add(p, q)
-            return jnp.stack([bn.restack(r.x.limbs), bn.restack(bk.fe_norm(r.y).limbs), bn.restack(bk.fe_norm(r.z).limbs)])
+            return _point_to_rows(bk.point_add(_rows_to_point(a), _rows_to_point(b)))
 
         got = bk.unpack_points(add(jnp.asarray(a), jnp.asarray(b)))
-        for p, q, g in zip(ps, qs, got):
-            want = host.g1_add(p, q)
-            assert g == want, (p, q)
+        for (name, (p_, q_)), g in zip(cases.items(), got):
+            assert g == host.g1_add(p_, q_), name
+            if name in want:
+                assert g == want[name], name
 
     def test_double_matches_host_incl_identity(self):
-        ps = [rand_point(), host.G1_GEN, None]
-        a = bk.pack_points(ps)
-
-        import jax
+        p = rand_point()
+        cases = {"2P": p, "2G": host.G1_GEN, "2O": None}
+        want = {"2P": host.g1_mul(p, 2), "2G": host.g1_mul(host.G1_GEN, 2), "2O": None}
+        a = bk.pack_points(list(cases.values()))
 
         @jax.jit
         def dbl(a):
-            p = bk.Point(bk.fe(bn.split(a[0])), bk.fe(bn.split(a[1])), bk.fe(bn.split(a[2])))
-            r = bk.point_double(p)
-            return jnp.stack([bn.restack(bk.fe_norm(r.x).limbs), bn.restack(bk.fe_norm(r.y).limbs), bn.restack(bk.fe_norm(r.z).limbs)])
+            return _point_to_rows(bk.point_double(_rows_to_point(a)))
 
         got = bk.unpack_points(dbl(jnp.asarray(a)))
-        for p, g in zip(ps, got):
-            assert g == host.g1_add(p, p), p
+        for (name, p_), g in zip(cases.items(), got):
+            assert g == host.g1_add(p_, p_) == want[name], name
+
+
+def _montgomery_loops(jaxpr) -> int:
+    """The looped-CIOS calls a run of `jaxpr` makes: every innermost loop
+    once for each trip of the loops around it (a `fori_loop` with static
+    bounds traces as a `scan` of that length)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        subs = jax.core.jaxprs_in_params(eqn.params)
+        inner = sum(_montgomery_loops(sub) for sub in subs)
+        if eqn.primitive.name == "scan":
+            n += eqn.params["length"] * inner if inner else 1
+        elif eqn.primitive.name == "while":
+            assert not inner, "a while loop around a loop has no static trip count"
+            n += 1
+        else:
+            n += inner
+    return n
+
+
+class TestLoopCounts:
+    """The engagement proof of the stacked multiplies (`Field.mul_many`),
+    decided at trace time: a point operation traces 3 Montgomery loops (it
+    traced 14 and 9), an MSM window of MSM_K terms 2 x 3 + 8 x 3 = 30 (it
+    traced 130).  Trace only: nothing is compiled or run."""
+
+    LANES = 8
+
+    def _point(self):
+        return jax.ShapeDtypeStruct((3, bn.NLIMBS, self.LANES), jnp.uint32)
+
+    @pytest.mark.parametrize("op,want", [("point_add", 3), ("point_double", 3)])
+    def test_point_op_traces_three_loops(self, op, want):
+        if op == "point_add":
+            fn = lambda p, q: _point_to_rows(bk.point_add(_rows_to_point(p), _rows_to_point(q)))
+            args = (self._point(), self._point())
+        else:
+            fn = lambda p: _point_to_rows(bk.point_double(_rows_to_point(p)))
+            args = (self._point(),)
+        assert _montgomery_loops(jax.make_jaxpr(fn)(*args).jaxpr) == want
+
+    def test_msm_window_traces_thirty_loops(self):
+        bases = jax.ShapeDtypeStruct((bk.MSM_K, 3, bn.NLIMBS, self.LANES), jnp.uint32)
+        scalars = jax.ShapeDtypeStruct((bk.MSM_K, bn.NLIMBS, self.LANES), jnp.uint32)
+        jaxpr = jax.make_jaxpr(bk.msm_batch_device)(bases, scalars).jaxpr
+        windows = [
+            e for e in jaxpr.eqns
+            if e.primitive.name == "scan" and e.params["length"] == bk.NUM_WINDOWS
+        ]
+        assert len(windows) == 1
+        (body,) = jax.core.jaxprs_in_params(windows[0].params)
+        assert bk.MSM_K == 8
+        assert _montgomery_loops(body) == 30
 
 
 class TestMSM:
